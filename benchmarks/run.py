@@ -66,6 +66,9 @@ def main() -> None:
              "are written to benchmarks/results/profile_<name>.txt",
     )
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     fast = not args.full
 
     from . import (

@@ -31,6 +31,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.core import ENGINES, CacheConfig, CrashTester, PersistPlan
 from repro.core.faults import FAULT_MODELS, get_fault_model
@@ -66,6 +67,7 @@ def main() -> None:
                          "recompute dispatch (default: REPRO_LANE_BATCH env "
                          "or 64); results are identical at any value")
     args = ap.parse_args()
+    enable_compile_cache()
 
     known = app_names()
     if args.app not in known:

@@ -16,6 +16,7 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CrashTester, SystemConfig, efficiency_with, efficiency_without
 from repro.core.artifacts import load_plan, save_plan
 from repro.core.workflow import WorkflowConfig, run_workflow
@@ -23,6 +24,7 @@ from repro.hpc.suite import ci_app, default_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     app = ci_app("cg")
     cache = default_cache(app)
     print(f"app={app.name} grid={app.grid} cache={cache.capacity_blocks} blocks")

@@ -23,6 +23,7 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     POLICIES,
     ArrivalProcess,
@@ -44,6 +45,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tests", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # ---- 1. campaign characterization of the decode loop -------------------
     app = ci_app("decode")
@@ -64,7 +66,7 @@ def main() -> None:
 
     # ---- 2. production: delta-persisted decode, killed and resumed ---------
     print("\nproduction server: delta persistence + mid-stream kill/resume")
-    workdir = "/tmp/repro_example_serve"
+    workdir = os.path.join(tempfile.gettempdir(), "repro_example_serve")
     shutil.rmtree(workdir, ignore_errors=True)
     serve_main([
         "--arch", "stablelm-1.6b",

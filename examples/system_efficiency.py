@@ -64,6 +64,9 @@ def main() -> None:
     ap.add_argument("--save-profile", default=None, metavar="PATH",
                     help="write the measured profile as a fingerprinted artifact")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.profile and args.workflow:
         ap.error("--profile and --workflow are mutually exclusive")
 

@@ -21,6 +21,7 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import WorkflowConfig, run_workflow, save_plan
 from repro.hpc.suite import ci_app, default_cache
 from repro.launch.train import main as train_main
@@ -30,8 +31,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--tests", type=int, default=20)
-    ap.add_argument("--workdir", default="/tmp/repro_example_train")
+    ap.add_argument("--workdir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_example_train"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     # ---- 1. campaign characterization of the training loop -----------------
     app = ci_app("lm-train")

@@ -62,6 +62,9 @@ def main() -> None:
                     help="campaign hot path (default vec); bit-for-bit "
                          "identical results either way")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.kill_after_shards and not args.workflow_store:
         ap.error("--kill-after-shards requires --workflow-store (the kill "
                  "fires from the store's shard callback)")

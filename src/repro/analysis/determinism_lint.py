@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np  # noqa: F401  (kernels build example args with numpy)
 
 import jax
+from jax.extend.core import Literal
 
 #: ops whose output element (i, ...) depends only on operand elements
 #: (i, ...) — lane axis passes straight through
@@ -87,7 +88,7 @@ class _Walker:
             env[var] = None
 
         def read(atom) -> Optional[int]:
-            if isinstance(atom, jax.core.Literal):
+            if isinstance(atom, Literal):
                 return None
             return env.get(atom, None)
 
@@ -186,7 +187,7 @@ class _Walker:
         if prim == "while":
             return self._while(eqn, lanes)
 
-        if prim in ("pjit", "closed_call", "core_call", "remat", "remat2",
+        if prim in ("jit", "closed_call", "core_call", "remat", "remat2",
                     "custom_jvp_call", "custom_vjp_call",
                     "custom_jvp_call_jaxpr", "checkpoint"):
             sub = self._single_sub(eqn)

@@ -31,6 +31,7 @@ from typing import Dict, FrozenSet, List, Mapping, Sequence, Tuple
 import numpy as np
 
 import jax
+from jax.extend.core import Literal
 
 from ..core.regions import Region, State
 
@@ -152,7 +153,7 @@ def _discrete_tags(eqn, in_info: Sequence[Info]) -> FrozenSet[str]:
 def walk_jaxpr(jaxpr, in_info: Sequence[Info]) -> List[Info]:
     """Propagate (deps, ops) from a jaxpr's invars to its outvars.
 
-    ``pjit``-style single-body higher-order primitives recurse exactly;
+    ``jit``-style single-body higher-order primitives recurse exactly;
     multi-branch/looping ones (``scan``/``while``/``cond``) join
     conservatively — all outputs depend on all data-dependent inputs, and
     every primitive inside counts as on-path.
@@ -164,7 +165,7 @@ def walk_jaxpr(jaxpr, in_info: Sequence[Info]) -> List[Info]:
         env[var] = _EMPTY
 
     def read(atom) -> Info:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             return _EMPTY
         return env.get(atom, _EMPTY)
 
@@ -177,7 +178,7 @@ def walk_jaxpr(jaxpr, in_info: Sequence[Info]) -> List[Info]:
             continue
         subs = _sub_jaxprs(eqn)
         if len(subs) == 1 and len(subs[0].invars) == len(eqn.invars):
-            # pjit / closed_call / custom_jvp-style: exact recursion
+            # jit / closed_call / custom_jvp-style: exact recursion
             out_infos = walk_jaxpr(subs[0], infos)
             for ov, info in zip(eqn.outvars, out_infos):
                 env[ov] = info

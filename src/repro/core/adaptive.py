@@ -244,7 +244,9 @@ def selection_invariant(
     settled — return it.  Any disagreement (or more than ``max_corners``
     corners) returns ``None``: keep sampling.
     """
-    varying = sorted(k for k, (lo, hi) in gain_boxes.items() if hi - lo > 1e-12)
+    # any width counts: a box as narrow as [0, 3e-14] straddles the knapsack's
+    # take-or-skip boundary at gain 0, so pinning it to one end is not safe
+    varying = sorted(k for k, (lo, hi) in gain_boxes.items() if hi > lo)
     if len(varying) > 0 and 2 ** len(varying) > max_corners:
         return None
 

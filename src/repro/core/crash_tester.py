@@ -110,6 +110,24 @@ class CrashRecord:
     weight: float = 1.0
 
 
+def records_match(a: Sequence[CrashRecord], b: Sequence[CrashRecord]) -> bool:
+    """Record-for-record equality of two campaigns, NaN == NaN (S3 records
+    carry a NaN metric): the engine-parity check between ``vec`` and the
+    ``ref`` oracle."""
+    if len(a) != len(b):
+        return False
+    for ra, rb in zip(a, b):
+        if (ra.iter_idx, ra.region_idx, ra.frac, ra.inconsistency,
+                ra.outcome, ra.extra_iters) != (
+                rb.iter_idx, rb.region_idx, rb.frac, rb.inconsistency,
+                rb.outcome, rb.extra_iters):
+            return False
+        ma, mb = ra.verify_metric, rb.verify_metric
+        if not (ma == mb or (np.isnan(ma) and np.isnan(mb))):
+            return False
+    return True
+
+
 @dataclass(frozen=True)
 class PlannedTest:
     """One pre-drawn crash test: campaign randomness is fully resolved up
@@ -744,28 +762,23 @@ class CrashTester:
         return fn(padded, *pextra)[:n]
 
     def _step_lanes(self, lanes: List["CrashTester._Lane"]) -> List["CrashTester._Lane"]:
-        """One batched iteration for every lane; on a batch-level failure,
-        falls back to per-lane serial steps and returns the lanes whose
-        serial step raised (their exception is theirs alone)."""
+        """One batched iteration for every lane.  A lane's own arithmetic
+        failure (an ``ArithmeticError`` such as an overflowing counter)
+        aborts the batch, so every lane is then stepped alone and the lanes
+        whose serial step raises are returned: the failure is theirs alone
+        (S3).  Any other error of the batched program — a failed compile, an
+        out-of-memory — is the device's and propagates."""
         app = self.app
         try:
             new_states = self._call_padded(
                 app.run_iteration_batch, [l.state for l in lanes]
             )
-        except Exception as e:  # noqa: BLE001 - attribute the failure per lane
-            import warnings
-
-            warnings.warn(
-                f"{app.name}: run_iteration_batch raised ({e!r}); falling "
-                f"back to per-lane serial steps — the vec engine is paying "
-                f"for a broken batched hook",
-                RuntimeWarning, stacklevel=2,
-            )
+        except ArithmeticError:
             failed = []
             for l in lanes:
                 try:
                     l.state = app.run_iteration(l.state)
-                except Exception:  # noqa: BLE001
+                except Exception:  # noqa: BLE001 - serial path: any failure is S3
                     failed.append(l)
             return failed
         for l, s in zip(lanes, new_states):
@@ -826,29 +839,19 @@ class CrashTester:
         # which also owns their exception capture (S3 semantics untouched)
         a_entry = [l for l in live if l.phase == "A"]
         if a_entry and app.supports_lane_driver:
-            try:
-                sts, nits, oks = app.advance_lanes(
-                    [l.state for l in a_entry], [l.it for l in a_entry],
-                    golden_iters,
-                )
-            except Exception as e:  # noqa: BLE001 - driver is an optimization
-                import warnings
-
-                warnings.warn(
-                    f"{app.name}: advance_lanes raised ({e!r}); falling back "
-                    f"to the host-loop phase A — the lane driver is broken",
-                    RuntimeWarning, stacklevel=2,
-                )
-            else:
-                for l, s, nit, ok in zip(a_entry, sts, nits, oks):
-                    if ok:
-                        l.state = s
-                        l.it = int(nit)
-                        l.phase = "B0"
-                    else:
-                        out[l.index] = self._restart_and_classify(*lanes[l.index])
-                        l.phase = "done"
-                live = [l for l in live if l.phase != "done"]
+            sts, nits, oks = app.advance_lanes(
+                [l.state for l in a_entry], [l.it for l in a_entry],
+                golden_iters,
+            )
+            for l, s, nit, ok in zip(a_entry, sts, nits, oks):
+                if ok:
+                    l.state = s
+                    l.it = int(nit)
+                    l.phase = "B0"
+                else:
+                    out[l.index] = self._restart_and_classify(*lanes[l.index])
+                    l.phase = "done"
+            live = [l for l in live if l.phase != "done"]
 
         active = live
         while active:
@@ -1373,8 +1376,22 @@ def campaign_executor(
 
     Submit shards with ``ex.submit(_shard_worker_run, key, plan, seed, ci,
     tests)`` — campaigns with distinct keys coexist on the same pool.
+
+    Workers are separate processes that each run regions on JAX's default
+    backend, so a pool exists only on the CPU: an accelerator belongs to the
+    one process that opened it (this one), and a worker would fail on its
+    lock or hang.
     """
+    import jax
     import multiprocessing as mp
+
+    backend = jax.default_backend()
+    if n_workers > 1 and backend != "cpu":
+        raise RuntimeError(
+            f"n_workers={n_workers} needs one process per worker, but the "
+            f"{backend!r} backend's device belongs to this process; run the "
+            f"campaign with n_workers=1"
+        )
 
     # spawn, not fork: jax is multithreaded and forked children
     # deadlock (REPRO_MP_START exists for non-jax substrates only)

@@ -9,9 +9,8 @@ bit-for-bit the mask :func:`repro.core.blocks.block_diff_mask` computes, so a
 delta flush writes a byte-identical NVM image to a whole-object flush
 (asserted by the differential test in ``tests/test_kernel_differential.py``).
 
-On hosts without the Pallas toolchain the CPU reference is used; the contract
-(and therefore the persisted image) is unchanged — only the bandwidth story
-differs.
+On a TPU the kernel is compiled; elsewhere Pallas interprets it.  The
+contract (and therefore the persisted image) is the same either way.
 """
 from __future__ import annotations
 
@@ -19,52 +18,28 @@ from typing import Optional
 
 import numpy as np
 
-from .blocks import DEFAULT_BLOCK_BYTES, _as_byte_view, block_diff_mask
-
-_KERNEL = None
-_KERNEL_FAILED = False
-
-
-def _kernel():
-    """Lazily import the Pallas op; cache the failure so hosts without the
-    toolchain pay the import cost once."""
-    global _KERNEL, _KERNEL_FAILED
-    if _KERNEL is None and not _KERNEL_FAILED:
-        try:
-            from ..kernels.delta_snapshot import dirty_block_mask
-
-            _KERNEL = dirty_block_mask
-        except Exception:
-            _KERNEL_FAILED = True
-    return _KERNEL
-
-
-def kernel_available() -> bool:
-    return _kernel() is not None
+from ..kernels.delta_snapshot import dirty_block_mask
+from .blocks import DEFAULT_BLOCK_BYTES, _as_byte_view
 
 
 def delta_block_mask(
     cur: np.ndarray,
     live: np.ndarray,
     block_bytes: int = DEFAULT_BLOCK_BYTES,
-    use_kernel: bool = True,
 ) -> np.ndarray:
     """Per-block "changed" mask between the NVM image and the live value.
 
     Same contract as :func:`repro.core.blocks.block_diff_mask` (bool
     ``(n_blocks,)``, final partial block is a real block, padding never reads
-    as dirty) — computed by the ``delta_snapshot`` kernel when available.
+    as dirty) — computed by the ``delta_snapshot`` kernel.
     """
-    k = _kernel() if use_kernel else None
-    if k is None:
-        return block_diff_mask(cur, live, block_bytes)
     av = _as_byte_view(np.asarray(cur))
     bv = _as_byte_view(np.asarray(live))
     if av.size != bv.size:
         raise ValueError("size mismatch")
     if av.size == 0:
         return np.zeros((0,), dtype=bool)
-    mask = np.asarray(k(bv, av, block_elems=int(block_bytes)))
+    mask = np.asarray(dirty_block_mask(bv, av, block_elems=int(block_bytes)))
     return mask.astype(bool)
 
 

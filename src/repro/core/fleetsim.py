@@ -117,12 +117,15 @@ class ArrivalProcess:
                             * math.sin(2.0 * math.pi * t / self.period + self.phase))
 
     def next_arrival(self, rng: np.random.Generator, t: float) -> float:
-        """The first arrival after ``t`` (Lewis thinning); inf if rate=0."""
+        """The first arrival after ``t`` (Lewis thinning); inf if rate=0, or
+        if the rate is so small that the draw overflows."""
         peak = self.rate * (1.0 + self.amplitude)
         if peak <= 0.0:
             return math.inf
         while True:
             t += float(rng.exponential(1.0 / peak))
+            if math.isinf(t):
+                return math.inf
             if float(rng.random()) * peak <= self.rate_at(t):
                 return t
 

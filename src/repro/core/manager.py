@@ -7,8 +7,8 @@ This is the framework-facing layer: given a train-state pytree and a
   (asynchronously, on a writer thread — a straggling host never blocks the
   step, and a skipped flush only increases staleness, which EasyCrash
   tolerates by construction);
-* performs delta flushes: only blocks that changed since the last flush move
-  (CPU stand-in for the ``delta_snapshot`` Pallas kernel);
+* performs delta flushes: only blocks that changed since the last flush
+  move, as flagged by the ``delta_snapshot`` Pallas kernel;
 * takes full coordinated checkpoints at the Young interval stretched by the
   measured recomputability (MTBF' = MTBF / (1 - R));
 * on restart, tries the EasyCrash path (arena image + acceptance
@@ -75,10 +75,10 @@ class FlushPolicy:
     (bounded staleness instead of a stalled step — straggler mitigation).
     ``persist_mode``: which blocks a flush moves to NVM —
     ``"auto"`` (arena's own byte diff), ``"delta"`` (incremental: changed
-    blocks only, detected by the ``delta_snapshot`` kernel, CPU reference off
-    TPU) or ``"full"`` (whole-object rewrite, the C/R-style baseline).  All
-    three produce byte-identical NVM images; they differ only in write
-    traffic, which ``ManagerStats.bytes_written`` measures.
+    blocks only, detected by the ``delta_snapshot`` kernel) or ``"full"``
+    (whole-object rewrite, the C/R-style baseline).  All three produce
+    byte-identical NVM images; they differ only in write traffic, which
+    ``ManagerStats.bytes_written`` measures.
     """
 
     leaves: Tuple[str, ...]

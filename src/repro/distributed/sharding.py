@@ -91,17 +91,10 @@ def get_rules() -> ShardingRules:
     return _active_rules
 
 
-def _current_mesh() -> Optional[Mesh]:
-    mesh = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
-    try:
-        from jax._src import mesh as mesh_lib
-
-        env_mesh = mesh_lib.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except Exception:
-        pass
-    return None
+def _current_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The mesh installed by ``jax.set_mesh``, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def with_logical(x: jax.Array, *logical: Optional[str]) -> jax.Array:

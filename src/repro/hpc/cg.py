@@ -20,23 +20,32 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.regions import IterativeApp, Region, State, VerifyResult
-from .common import laplacian_apply, rel_residual
+from .common import laplacian_apply, rel_residual, tree_sum
 
 
 @jax.jit
 def _dot(a, b):
-    return jnp.sum(a * b)
+    """Dot products over the last axis, ``(..., n) -> (..., 1)``, summed in
+    :func:`tree_sum`'s fixed order: a serial region's vector and a stack of
+    lanes round alike."""
+    return tree_sum(a * b)[..., None]
+
+
+@jax.jit
+def _ratio(num, den):
+    """``num / den`` in float32, 0 where ``den == 0`` — the one division both
+    paths share, so it rounds alike even where float32 division is not
+    correctly rounded (on the CPU it is, and equals the float64 quotient
+    rounded to float32)."""
+    return jnp.where(den != 0.0, num / den, 0.0)
 
 
 # Batched lane hooks for the vectorized campaign engine.  CG is matrix-free
 # (the Laplacian is a stencil), so the whole iteration is elementwise chains
 # plus per-lane reductions over the *data* axis — no ``dot_general`` — and
-# vmapping is bitwise-safe.  The serial path's host-side float64 scalar math
-# (``alpha = float(rho) / float(pq)`` then NumPy's value-based cast back to
-# float32) is replicated by plain float32 division in-jit: for float32
-# operands, dividing in float64 and rounding the quotient to float32 equals
-# the direct float32 division (double rounding is innocuous at 53 >= 2*24+2,
-# Figueroa 1995), so the two pipelines agree to the bit.
+# vmapping is bitwise-safe.  Both paths take their dot products through
+# :func:`_dot` and their scalar divisions through :func:`_ratio`, so they
+# sum in one order and divide with one operation on any backend.
 def _cg_step_core(a: dict, b: jnp.ndarray, one: jnp.ndarray, g: int, rr_every: int) -> dict:
     """One CG iteration (matvec, x-update, r-update, p-update) on stacked
     lanes; mirrors the serial region chain value-for-value.
@@ -52,9 +61,9 @@ def _cg_step_core(a: dict, b: jnp.ndarray, one: jnp.ndarray, g: int, rr_every: i
     """
     p, r, x = a["p"], a["r"], a["x"]
     q = jax.vmap(lambda v: laplacian_apply(v, g))(p)
-    pq = jnp.sum(p * q, axis=1, keepdims=True)
+    pq = _dot(p, q)
     rho = a["rho"]
-    alpha = jnp.where(pq != 0.0, rho / pq, 0.0)
+    alpha = _ratio(rho, pq)
     x = x + (alpha * p) * one
     kk = a["k"]
     use_rr = ((kk + 1) % rr_every) == 0 if rr_every else jnp.zeros_like(kk, bool)
@@ -62,8 +71,8 @@ def _cg_step_core(a: dict, b: jnp.ndarray, one: jnp.ndarray, g: int, rr_every: i
     r_true = b - jax.vmap(lambda v: laplacian_apply(v, g))(x)
     r = jnp.where(use_rr, r_true, r - (alpha * q) * one)
     rho_prev = rho
-    rho = jnp.sum(r * r, axis=1, keepdims=True)
-    beta = jnp.where(rho_prev != 0.0, rho / rho_prev, 0.0)
+    rho = _dot(r, r)
+    beta = _ratio(rho, rho_prev)
     p = jnp.where(use_rr, r, r + (beta * p) * one)
     return {"x": x, "r": r, "p": p, "q": q, "rho": rho,
             "rho_prev": rho_prev, "alpha": alpha, "k": kk + 1}
@@ -130,8 +139,8 @@ class CGApp(IterativeApp):
 
     def _x_update(self, s: State) -> State:
         s = dict(s)
-        pq = float(_dot(jnp.asarray(s["p"]), jnp.asarray(s["q"])))
-        alpha = float(s["rho"][0]) / pq if pq != 0.0 else 0.0
+        pq = _dot(jnp.asarray(s["p"]), jnp.asarray(s["q"]))
+        alpha = float(_ratio(jnp.asarray(s["rho"]), pq)[0])
         s["alpha"] = np.array([alpha], np.float32)
         s["x"] = s["x"] + alpha * s["p"]
         return s
@@ -146,7 +155,7 @@ class CGApp(IterativeApp):
             r = s["r"] - s["alpha"][0] * s["q"]
         s["r"] = r.astype(np.float32)
         s["rho_prev"] = s["rho"].copy()
-        s["rho"] = np.array([float(_dot(jnp.asarray(r), jnp.asarray(r)))], np.float32)
+        s["rho"] = np.array([float(_dot(jnp.asarray(r), jnp.asarray(r))[0])], np.float32)
         return s
 
     def _p_update(self, s: State) -> State:
@@ -156,8 +165,7 @@ class CGApp(IterativeApp):
             # restart direction after residual replacement
             s["p"] = s["r"].copy()
         else:
-            denom = float(s["rho_prev"][0])
-            beta = float(s["rho"][0]) / denom if denom != 0.0 else 0.0
+            beta = float(_ratio(jnp.asarray(s["rho"]), jnp.asarray(s["rho_prev"]))[0])
             s["p"] = s["r"] + beta * s["p"]
         s["k"] = s["k"] + 1
         return s

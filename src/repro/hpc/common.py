@@ -8,6 +8,27 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def tree_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Sum over ``axis`` in one fixed pairwise order, written out as
+    elementwise adds of halves (zero-padded to a power of two).
+
+    A reduce leaves its order to the compiler, which picks it per shape and
+    per backend — on a TPU a 1-D sum, a one-row sum and an eight-row sum of
+    the same numbers all round differently — so a serial region and the same
+    computation on a stack of lanes would disagree.  Adds of slices round
+    the same for any batch shape on any backend."""
+    x = jnp.moveaxis(x, axis, -1)
+    n = x.shape[-1]
+    width = 1
+    while width < n:
+        width *= 2
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while width > 1:
+        width //= 2
+        x = x[..., :width] + x[..., width:]
+    return x[..., 0]
+
+
 @partial(jax.jit, static_argnames=("g",))
 def laplacian_apply(x_flat: jnp.ndarray, g: int) -> jnp.ndarray:
     """y = A x for the 2-D 5-point Laplacian (Dirichlet) on a g x g grid.
@@ -40,11 +61,17 @@ def jacobi_sweep(u_flat: jnp.ndarray, b_flat: jnp.ndarray, g: int, omega: float 
 
 @partial(jax.jit, static_argnames=("g",))
 def restrict(r_flat: jnp.ndarray, g: int) -> jnp.ndarray:
-    """Full-weighting restriction g x g -> g/2 x g/2 (g even)."""
+    """Full-weighting restriction g x g -> g/2 x g/2 (g even).
+
+    The four children are summed in one fixed order, written out: a
+    ``mean`` leaves the order to the compiler, which picks it per program
+    and per backend, so a restriction fused into a larger (batched) program
+    would round differently from this one alone."""
     r = r_flat.reshape(g, g)
     gc = g // 2
     r = r[: gc * 2, : gc * 2].reshape(gc, 2, gc, 2)
-    return r.mean(axis=(1, 3)).reshape(-1)
+    s = ((r[:, 0, :, 0] + r[:, 0, :, 1]) + r[:, 1, :, 0]) + r[:, 1, :, 1]
+    return (s / 4.0).reshape(-1)
 
 
 @partial(jax.jit, static_argnames=("g",))

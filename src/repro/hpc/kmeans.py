@@ -18,34 +18,38 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.regions import IterativeApp, Region, State, VerifyResult
+from .common import tree_sum
+
+
+# Every float sum goes through :func:`tree_sum`, whose order no compiler
+# picks: a serial region and the same kernel on a stack of lanes (vmapped,
+# or inside the lane driver) then round alike on any backend.
+def _dist2(points: jnp.ndarray, centroids: jnp.ndarray) -> jnp.ndarray:
+    """Squared distances, ``(n, d), (k, d) -> (n, k)``."""
+    return tree_sum((points[:, None, :] - centroids[None, :, :]) ** 2)
 
 
 @jax.jit
 def _assign(points: jnp.ndarray, centroids: jnp.ndarray) -> jnp.ndarray:
-    d2 = jnp.sum((points[:, None, :] - centroids[None, :, :]) ** 2, axis=-1)
-    return jnp.argmin(d2, axis=1).astype(jnp.int32)
+    return jnp.argmin(_dist2(points, centroids), axis=1).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("k",))
 def _update(points: jnp.ndarray, assign: jnp.ndarray, centroids: jnp.ndarray, k: int) -> jnp.ndarray:
     one_hot = jax.nn.one_hot(assign, k, dtype=points.dtype)          # (n, k)
-    sums = one_hot.T @ points                                        # (k, d)
-    counts = one_hot.sum(axis=0)[:, None]                            # (k, 1)
+    sums = tree_sum(one_hot[:, :, None] * points[:, None, :], axis=0)  # (k, d)
+    counts = one_hot.sum(axis=0)[:, None]     # (k, 1), exact in any order
     return jnp.where(counts > 0, sums / jnp.maximum(counts, 1.0), centroids)
 
 
 @jax.jit
 def _inertia(points: jnp.ndarray, centroids: jnp.ndarray) -> jnp.ndarray:
-    d2 = jnp.sum((points[:, None, :] - centroids[None, :, :]) ** 2, axis=-1)
-    return jnp.sum(jnp.min(d2, axis=1))
+    return tree_sum(jnp.min(_dist2(points, centroids), axis=1))
 
 
-# Batched lane hooks for the vectorized campaign engine.  The assignment and
-# inertia kernels are elementwise chains with per-lane reductions over
-# *non-lane* axes (distance sum over dims, argmin/min over clusters), so
-# vmapping them is bitwise-safe.  The centroid update contracts
-# ``one_hot.T @ points`` — a matmul whose vmap would become a batched
-# ``dot_general`` with a different reduction tiling — so lanes go through
+# Batched lane hooks for the vectorized campaign engine: the kernels are
+# elementwise chains with per-lane reductions over *non-lane* axes, so
+# vmapping them is bitwise-safe.  The centroid update goes through
 # ``lax.map``: one dispatch, per-lane HLO identical to ``_update``.
 def _step_core(points: jnp.ndarray, cent_b: jnp.ndarray, k: int):
     assign_b = jax.vmap(lambda c: _assign(points, c))(cent_b)

@@ -26,10 +26,11 @@ from .common import jacobi_sweep, laplacian_apply, prolong, rel_residual, restri
 # Batched lane hooks for the vectorized campaign engine.  The V-cycle is
 # stencils, grid-transfer reshapes and elementwise chains — no ``dot_general``
 # — so vmapping is bitwise-safe.  Two serial host-side roundings must survive
-# the move in-program: ``restrict`` materializes ``0.25 * sum`` as its own
-# program root, and the coarse right-hand side ``4.0 * rc`` is an eager
+# the move in-program: ``restrict`` materializes ``sum / 4`` as its own
+# program root (its sum in a written-out order, which no fusion may pick
+# for it), and the coarse right-hand side ``4.0 * rc`` is an eager
 # standalone multiply.  Inside one XLA program the first would reassociate
-# with the second (``4 * (0.25 * s) -> s``) and the result would contract
+# with the second (``4 * (s / 4) -> s``) and the result would contract
 # into the first Jacobi ``b + nb`` as an FMA; multiplying each by ``one`` — a
 # *runtime* 1.0f the compiler cannot fold — pins both roundings exactly where
 # the serial path takes them (see :func:`repro.hpc.cg._cg_step_core`).

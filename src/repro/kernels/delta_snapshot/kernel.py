@@ -4,12 +4,16 @@ The paper's mechanism relies on CLWB being ~free for clean cache blocks; TPUs
 have no dirty bit, so we *compute* it: compare the live shard against the
 last-persisted snapshot at flush-block granularity and emit a per-block
 changed mask.  The host then DMAs only dirty blocks (see
-``repro.core.manager``).  Bandwidth-bound VPU compare + horizontal reduce:
-one pass over 2x the shard bytes, no MXU.
+``repro.core.manager``).  Bandwidth-bound: one pass over 2x the shard bytes.
 
-Grid: 1-D over tiles of ``rows_per_tile`` blocks; each block is
-``block_elems`` contiguous elements (default 256 elems = 1 KiB f32, the
-production flush-block size).
+Layout (lane-dense): both operands arrive as 32-bit words in ``(rows, C)``
+tiles, ``C = max(128, cols)`` lanes, where every flush block occupies
+``cols`` consecutive words of one row (``cols`` divides 128 or is a multiple
+of it), so one row holds ``k = C // cols`` blocks.  The kernel casts the
+word-wise ``!=`` to 0/1 and sums each block's segment with one ``(k, C) x
+(tr, C)^T`` segment-indicator matmul, which lands the per-block counts as a
+lane-dense ``(k, tr)`` int32 tile: no bool reduction, no rank-1 block, no
+relayout.  Counts are small integers, exact at any matmul precision.
 """
 from __future__ import annotations
 
@@ -19,33 +23,49 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_ELEMS = 256
-DEFAULT_ROWS_PER_TILE = 64
+#: words per (rows, C) input tile: 1 MiB per operand buffer
+TILE_WORDS = 1 << 18
 
 
-def _delta_kernel(x_ref, prev_ref, o_ref):
-    x = x_ref[...]
-    p = prev_ref[...]
-    diff = (x != p).any(axis=1)
-    o_ref[...] = diff.astype(jnp.int32)
+def _delta_kernel(x_ref, p_ref, o_ref, *, cols: int):
+    d = jnp.where(x_ref[...] != p_ref[...], 1.0, 0.0).astype(jnp.float32)
+    k, c = o_ref.shape[0], d.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (k, c), 1)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (k, c), 0)
+    seg = jnp.where(lane // cols == blk, 1.0, 0.0).astype(jnp.float32)
+    counts = jax.lax.dot_general(
+        seg, d, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    o_ref[...] = jnp.where(counts > 0.0, 1, 0).astype(jnp.int32)
 
 
-def dirty_block_mask_blocks(
-    x: jax.Array, prev: jax.Array,
-    *, rows_per_tile: int = DEFAULT_ROWS_PER_TILE, interpret: bool = True,
+def tile_rows(rows: int, lanes: int) -> int:
+    """Rows per grid tile: one tile when everything fits, else a multiple of
+    128 (the output tile's lane dimension) near :data:`TILE_WORDS`."""
+    tr = max(128, (TILE_WORDS // lanes) // 128 * 128)
+    if rows <= tr:
+        return -(-rows // 8) * 8
+    return tr
+
+
+def dirty_block_mask_words(
+    x: jax.Array, prev: jax.Array, *, cols: int, interpret: bool = True,
 ) -> jax.Array:
-    """x, prev: (n_blocks, block_elems) -> int32 (n_blocks,) changed mask."""
-    n, e = x.shape
-    rt = min(rows_per_tile, n)
-    assert n % rt == 0
+    """x, prev: int32 ``(rows, C)`` word tiles, ``rows`` a multiple of
+    :func:`tile_rows` -> int32 ``(k, rows)``; entry ``[j, r]`` flags block
+    ``r * k + j`` (``k = C // cols``)."""
+    rows, lanes = x.shape
+    k = lanes // cols
+    tr = tile_rows(rows, lanes)
+    assert rows % tr == 0 and lanes % cols == 0, (rows, tr, lanes, cols)
     return pl.pallas_call(
-        _delta_kernel,
-        grid=(n // rt,),
+        functools.partial(_delta_kernel, cols=cols),
+        grid=(rows // tr,),
         in_specs=[
-            pl.BlockSpec((rt, e), lambda i: (i, 0)),
-            pl.BlockSpec((rt, e), lambda i: (i, 0)),
+            pl.BlockSpec((tr, lanes), lambda i: (i, 0)),
+            pl.BlockSpec((tr, lanes), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((rt,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        out_specs=pl.BlockSpec((k, tr), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((k, rows), jnp.int32),
         interpret=interpret,
     )(x, prev)

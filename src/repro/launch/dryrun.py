@@ -111,7 +111,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, impl: str = "reference"
     n_dev = mesh.devices.size
     t0 = time.time()
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             if shape.mode == "train":
                 step = make_train_step(cfg, impl=impl)
                 state = abstract_train_state(cfg)

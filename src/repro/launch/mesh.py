@@ -14,12 +14,9 @@ from jax.sharding import Mesh
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (TypeError, AttributeError):  # older jax: no axis_types kwarg /
-        return jax.make_mesh(shape, axes)  # no jax.sharding.AxisType at all
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import os
+import tempfile
 import time
 from typing import Dict
 
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import get_arch
 from ..core.arena import NVMArena
 from ..core.manager import EasyCrashManager, FlushPolicy, flatten_state
@@ -101,6 +103,7 @@ def run(args) -> Dict[str, float]:
     }
     print("[done]", stats)
     mgr.close()
+    stats["tokens"] = out
     return stats
 
 
@@ -182,7 +185,9 @@ def _splice_cache(cfg, full_cache, prefill_cache, prompt_len: int):
     return out
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> Dict[str, object]:
+    """Serve, restart once after an injected failure, and return the last
+    run's stats; ``stats["tokens"]`` is the final token buffer."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--full-size", action="store_true")
@@ -195,7 +200,8 @@ def main(argv=None) -> None:
                     choices=("auto", "delta", "full"),
                     help="flush granularity: arena byte diff / delta_snapshot "
                          "kernel (changed blocks only) / whole-object rewrite")
-    ap.add_argument("--workdir", default="/tmp/repro_serve")
+    ap.add_argument("--workdir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_serve"))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--inject-failure-at", type=int, default=0)
     ap.add_argument("--fleet", action="store_true",
@@ -210,6 +216,7 @@ def main(argv=None) -> None:
                     help="per-replica MTBF, seconds")
     ap.add_argument("--fleet-horizon", type=float, default=1800.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     try:
         stats = run(args)
     except SimulatedFailure as e:
@@ -218,6 +225,7 @@ def main(argv=None) -> None:
         stats = run(args)
     if args.fleet:
         fleet_report(stats, args)
+    return stats
 
 
 if __name__ == "__main__":

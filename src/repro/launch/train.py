@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 import os
+import tempfile
 import time
 from typing import Dict, Optional
 
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..checkpoint import CheckpointConfig, CheckpointManager
+from ..compile_cache import enable_compile_cache
 from ..configs import get_arch
 from ..core.arena import NVMArena
 from ..core.manager import EasyCrashManager, FlushPolicy, flatten_state, unflatten_state
@@ -179,7 +181,8 @@ def main(argv=None) -> None:
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--workdir", default="/tmp/repro_train")
+    ap.add_argument("--workdir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_train"))
     ap.add_argument("--flush-every", type=int, default=1)
     ap.add_argument("--sync-flush", action="store_true")
     ap.add_argument("--persist-mode", default="auto",
@@ -194,6 +197,7 @@ def main(argv=None) -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--max-restarts", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     restarts = 0
     while True:

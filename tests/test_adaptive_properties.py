@@ -161,6 +161,15 @@ def test_stopping_never_fires_while_decision_ambiguous(inst, data):
         ).plan_freqs() == decision, gains
 
 
+@pytest.mark.parametrize("point", [0.0, 3e-14])
+def test_hair_thin_box_across_zero_gain_is_ambiguous(point):
+    """A gain box of width 3e-14 from 0 up holds plans that skip the region
+    (gain 0) and plans that take it (gain > 0): no decision, wherever the
+    point estimate sits (hypothesis found both cases)."""
+    assert selection_invariant({0: point}, {0: (0.0, 3e-14)}, {0: 0.03125},
+                               0.0, t_s=0.0625, tau=0.5) is None
+
+
 def test_max_corners_guard_never_claims_invariance():
     point = {k: 0.5 for k in range(3)}
     boxes = {k: (0.1, 0.9) for k in range(3)}

@@ -19,6 +19,7 @@ from repro.core.cache_sim import (
     simulate_window,
     simulate_window_vec,
 )
+from repro.core.crash_tester import records_match as _records_equal
 from repro.core.faults import FAULT_MODELS, get_fault_model
 from repro.core.trace_cache import WindowTraceCache
 from repro.hpc.suite import ci_app, default_cache
@@ -59,22 +60,6 @@ def _campaign(app, engine, fault=None, n_tests=8, workers=1, plan=None, tc=None)
         trace_cache=tc if tc is not None else WindowTraceCache(0, 0),
     )
     return tester.run_campaign(n_tests, n_workers=workers)
-
-
-def _records_equal(a, b):
-    """CrashRecord equality with NaN == NaN (S3 metrics are NaN)."""
-    if len(a) != len(b):
-        return False
-    for ra, rb in zip(a, b):
-        if (ra.iter_idx, ra.region_idx, ra.frac, ra.inconsistency,
-                ra.outcome, ra.extra_iters) != (
-                rb.iter_idx, rb.region_idx, rb.frac, rb.inconsistency,
-                rb.outcome, rb.extra_iters):
-            return False
-        ma, mb = ra.verify_metric, rb.verify_metric
-        if not (ma == mb or (np.isnan(ma) and np.isnan(mb))):
-            return False
-    return True
 
 
 def _assert_traces_equal(a, b):

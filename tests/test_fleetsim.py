@@ -285,3 +285,17 @@ def test_persist_tax_slows_easycrash_service():
     c0 = simulate_fleet("checkpoint", cfg0)
     c1 = simulate_fleet("checkpoint", cfg1)
     assert c0 == c1
+
+
+def test_tiny_rate_arrival_overflows_to_never():
+    """A rate so small that the exponential draw overflows means no arrival,
+    not a math domain error in the diurnal rate (the conservation property's
+    hypothesis search drew such a rate)."""
+    import math
+
+    import numpy as np
+
+    from repro.core.fleetsim import ArrivalProcess
+
+    arr = ArrivalProcess(rate=2.225073858507203e-309, amplitude=0.5)
+    assert arr.next_arrival(np.random.default_rng(0), 0.0) == math.inf

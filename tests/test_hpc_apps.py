@@ -63,3 +63,34 @@ def test_registry():
     assert set(app_names()) == set(CI_SIZES)
     with pytest.raises(KeyError):
         get_app("nope")
+
+
+@pytest.mark.parametrize("n", [1, 3, 8, 1000, 2304])
+def test_tree_sum_order_is_shape_independent(n):
+    """tree_sum rounds the same for one vector, each row of a stack, and a
+    vmapped stack — the property the batched lanes rely on — and sums to
+    the exact total within float32 rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.hpc.common import tree_sum
+
+    rng = np.random.default_rng(n)
+    rows = rng.standard_normal((5, n)).astype(np.float32)
+    one = np.array([np.asarray(jax.jit(tree_sum)(jnp.asarray(r))) for r in rows])
+    stacked = np.asarray(jax.jit(tree_sum)(jnp.asarray(rows)))
+    mapped = np.asarray(jax.jit(jax.vmap(tree_sum))(jnp.asarray(rows)))
+    assert one.view(np.uint32).tolist() == stacked.view(np.uint32).tolist()
+    assert one.view(np.uint32).tolist() == mapped.view(np.uint32).tolist()
+    np.testing.assert_allclose(one, rows.astype(np.float64).sum(axis=1), rtol=1e-5, atol=1e-5)
+
+
+def test_tree_sum_adds_halves():
+    """The order is the documented one: zero-pad to a power of two, then add
+    the upper half onto the lower — [a, b, c] sums as (a + c) + b."""
+    import jax.numpy as jnp
+
+    from repro.hpc.common import tree_sum
+
+    x = jnp.asarray([1e8, 1.0, -1e8], jnp.float32)
+    assert float(tree_sum(x)) == 1.0  # left to right it would be 0.0

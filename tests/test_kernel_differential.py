@@ -17,7 +17,7 @@ pytest.importorskip(
 
 from repro.core.arena import NVMArena
 from repro.core.blocks import block_diff_mask
-from repro.core.delta_persist import delta_block_mask, kernel_available
+from repro.core.delta_persist import delta_block_mask
 from repro.core.manager import EasyCrashManager, FlushPolicy
 from repro.kernels.delta_snapshot.ops import dirty_block_mask
 from repro.kernels.delta_snapshot.ref import dirty_block_mask_reference
@@ -77,6 +77,47 @@ def test_dirty_block_mask_agrees_with_cpu_block_diff(n, block_bytes):
     np.testing.assert_array_equal(kernel_mask, cpu_mask)
 
 
+@pytest.mark.parametrize("dtype,block_elems,n", [
+    pytest.param(np.uint8, 64, 64 * 64, id="u8-one-tile-even"),
+    pytest.param(np.uint8, 64, 64 * 129 - 5, id="u8-odd-blocks-partial-tail"),
+    pytest.param(np.uint8, 64, 64 * 16384 + 64 * 9, id="u8-two-tiles"),
+    pytest.param(np.uint8, 7, 1000, id="u8-unpacked-block"),
+    pytest.param(np.uint8, 12, 999, id="u8-3-word-block"),
+    pytest.param("bfloat16", 64, 6001, id="bf16-packed"),
+    pytest.param(np.float32, 24, 3001, id="f32-24-word-block"),
+    pytest.param(np.float32, 1024, 5000, id="f32-block-spans-lanes"),
+    pytest.param(np.float32, 1, 301, id="f32-one-word-block"),
+])
+def test_dirty_block_mask_tiling(dtype, block_elems, n):
+    """The lane-dense word layout against the byte-level block_diff_mask:
+    packed and zero-extended blocks, blocks narrower and wider than a
+    128-lane row, odd block counts, partial tail blocks, several tiles."""
+    dtype = jnp.bfloat16.dtype if dtype == "bfloat16" else np.dtype(dtype)
+    rng = np.random.default_rng(n)
+    x = rng.integers(0, 256, n * dtype.itemsize, dtype=np.uint8).view(dtype)
+    p = x.copy().view(np.uint8)
+    edits = rng.choice(p.size, size=min(11, p.size), replace=False)
+    p[edits] ^= rng.integers(1, 256, edits.size, dtype=np.uint8)
+    p = p.view(dtype)
+    got = np.asarray(dirty_block_mask(jnp.asarray(x), jnp.asarray(p), block_elems=block_elems))
+    want = block_diff_mask(x, p, block_bytes=block_elems * dtype.itemsize)
+    np.testing.assert_array_equal(got.astype(bool), want)
+    assert got.dtype == np.int32 and want.any()
+    clean = np.asarray(dirty_block_mask(jnp.asarray(x), jnp.asarray(x), block_elems=block_elems))
+    assert clean.shape == want.shape and not clean.any()
+
+
+def test_dirty_block_mask_compares_bits():
+    """Dirty means changed bits: a NaN kept as is is clean, a sign flip of
+    zero is dirty (value comparison would say the opposite of both)."""
+    x = np.array([np.nan, 0.0, 1.0, 2.0], np.float32)
+    p = np.array([np.nan, -0.0, 1.0, 2.0], np.float32)
+    got = np.asarray(dirty_block_mask(jnp.asarray(x), jnp.asarray(p), block_elems=1))
+    assert got.tolist() == [0, 1, 0, 0]
+    ref = dirty_block_mask_reference(jnp.asarray(x)[:, None], jnp.asarray(p)[:, None])
+    assert np.asarray(ref).tolist() == [0, 1, 0, 0]
+
+
 # ------------------------------------------------------- delta persistence
 def _persist_series(n, dtype, rng):
     """A value trajectory that touches one block per step plus the tail."""
@@ -101,7 +142,6 @@ def test_delta_persist_image_matches_full(n, dtype):
     while writing no more blocks."""
     if dtype == "bfloat16":
         dtype = jnp.bfloat16.dtype
-    assert kernel_available()
     rng = np.random.default_rng(n)
     series = _persist_series(n, dtype, rng)
 
