@@ -9,10 +9,12 @@ lines; any failure exits non-zero before the last line is printed:
 2. kernel  — the ``delta_snapshot`` dirty-block mask, compiled (its HLO must
    hold a ``tpu_custom_call``), on byte views of cache-sized buffers with
    even and odd block counts, equal to ``block_diff_mask``;
-3. campaign — ``run_workflow`` to a persist plan on one suite app at
+3. numerics — the float32 summation and division rounding that engine
+   parity rests on;
+4. campaign — ``run_workflow`` to a persist plan on one suite app at
    ``BENCH_SIZES``, then per HPC app a small campaign on the ``vec`` engine
    and on the ``ref`` oracle, which must agree record for record;
-4. server  — ``repro.launch.serve`` on stablelm-1.6b at full width with
+5. server  — ``repro.launch.serve`` on stablelm-1.6b at full width with
    delta persistence: an uninterrupted run, a run killed halfway that
    resumes from its arena without prefill and must end on the same token
    buffer, and a whole-object-rewrite run for the byte comparison.
@@ -38,10 +40,6 @@ sys.path.insert(0, str(ROOT / "src"))
 
 #: scratch space for the server's arenas (git-ignored, emptied per run)
 OUT = ROOT / ".smoke_out"
-
-#: HPC apps whose vec engine is known to disagree with the ref oracle on
-#: this chip; they are still run and printed, but do not fail the smoke
-PARITY_KNOWN_BAD: tuple = ()
 
 HPC_APPS = ("cg", "heat", "kmeans", "mg", "montecarlo", "pagerank", "sor")
 WORKFLOW_APP = "kmeans"
@@ -100,6 +98,44 @@ def phase_kernel():
               f"equal=ok tpu_custom_call=True call_s={dt:.6f}")
 
 
+def phase_numerics():
+    """The chip's float32 rounding that engine parity rests on.  A serial
+    region reduces one vector and a batched hook reduces rows of a lane
+    stack: ``tree_sum`` must round alike for a 1-D vector, one row and eight
+    rows (asserted); whether a plain ``jnp.sum`` does, and how many float32
+    quotients differ from the correctly rounded ones, is printed — the
+    reasons cg and kmeans sum through ``tree_sum`` and cg divides on the
+    device on both paths."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.hpc.common import tree_sum
+
+    rng = np.random.default_rng(0)
+    a = rng.standard_normal((8, 48 * 48)).astype(np.float32)
+    b = rng.standard_normal((8, 48 * 48)).astype(np.float32)
+
+    def by_shape(reduce):
+        """Row dot products summed as 1-D vectors, one row, eight rows."""
+        fn = jax.jit(lambda x, y: reduce(x * y))
+        one_d = np.stack([np.asarray(fn(a[i], b[i])) for i in range(8)])
+        one_row = np.stack([np.asarray(fn(a[i:i + 1], b[i:i + 1]))[0] for i in range(8)])
+        return one_d, one_row, np.asarray(fn(a, b))
+
+    t1, tr, t8 = by_shape(tree_sum)
+    if not (np.array_equal(t1, tr) and np.array_equal(tr, t8)):
+        raise AssertionError("tree_sum rounds differently for 1-D, one-row and eight-row sums")
+    s1, sr, s8 = by_shape(lambda x: jnp.sum(x, axis=-1))
+    num = rng.standard_normal(100_000).astype(np.float32)
+    den = (rng.standard_normal(100_000) * 10.0 ** rng.integers(-3, 4, 100_000)).astype(np.float32)
+    quot = np.asarray(jax.jit(jnp.divide)(num, den))
+    exact = (num.astype(np.float64) / den.astype(np.float64)).astype(np.float32)
+    print(f"[numerics] tree_sum_1d_row_8rows_equal=ok "
+          f"jnp_sum_1d_eq_row={np.array_equal(s1, sr)} "
+          f"jnp_sum_row_eq_8rows={np.array_equal(sr, s8)} "
+          f"f32_div_differs_from_correctly_rounded={int(np.sum(quot != exact))}/{num.size}")
+
+
 def phase_campaign():
     """Workflow to a persist plan on one app, then vec/ref record parity on
     every HPC app.  Returns the apps whose parity failed."""
@@ -119,7 +155,6 @@ def phase_campaign():
           f"plan_regions={sorted(wf.plan.region_freq.items())} "
           + " ".join(f"{k}={fr.get(k, 0.0):.4f}" for k in ("S1", "S2", "S3", "S4")))
 
-    print(f"[parity] parity_known_bad={list(PARITY_KNOWN_BAD)}")
     failed = []
     for name in HPC_APPS:
         runs, secs = {}, {}
@@ -135,9 +170,8 @@ def phase_campaign():
         ok = records_match(runs["ref"].records, runs["vec"].records)
         if not ok:
             failed.append(name)
-        label = " (parity_known_bad)" if name in PARITY_KNOWN_BAD else ""
         print(f"[parity] app={name} tests={PARITY_TESTS} vec_s={secs['vec']:.3f} "
-              f"ref_s={secs['ref']:.3f} parity={'ok' if ok else 'FAIL'}{label}")
+              f"ref_s={secs['ref']:.3f} parity={'ok' if ok else 'FAIL'}")
     return failed
 
 
@@ -177,9 +211,8 @@ def _campaign_phase():
         warnings.filterwarnings("ignore", message=".*encountered in",
                                 category=RuntimeWarning)
         failed = phase_campaign()
-    bad = [a for a in failed if a not in PARITY_KNOWN_BAD]
-    if bad:
-        raise AssertionError(f"vec != ref records on {bad}")
+    if failed:
+        raise AssertionError(f"vec != ref records on {failed}")
 
 
 def main() -> int:
@@ -197,7 +230,7 @@ def main() -> int:
     ok = True
     try:
         # every phase runs even after another failed, so one run shows all
-        for phase in (phase_kernel, _campaign_phase, phase_server):
+        for phase in (phase_kernel, phase_numerics, _campaign_phase, phase_server):
             try:
                 phase()
             except Exception:  # noqa: BLE001 - any phase failure fails the smoke
@@ -206,6 +239,8 @@ def main() -> int:
                 ok = False
     finally:
         shutil.rmtree(OUT, ignore_errors=True)
+    cached = sum(f.stat().st_size for f in Path(cache_dir).rglob("*") if f.is_file())
+    print(f"[device] compile_cache_bytes={cached}")
     if not ok:
         return 1
     print(json.dumps({"ok": True, "device": device}))

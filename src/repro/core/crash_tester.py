@@ -42,7 +42,9 @@ from .cache_sim import (
     simulate_window,
 )
 from .faults import FaultModel, PowerFail
-from .regions import IterativeApp, Region, State, VerifyResult, object_blocks
+from .regions import (
+    DEVICE_ERRORS, IterativeApp, Region, State, VerifyResult, object_blocks,
+)
 from .trace_cache import WindowPayload, WindowTraceCache, shared_trace_cache
 
 
@@ -778,7 +780,9 @@ class CrashTester:
             for l in lanes:
                 try:
                     l.state = app.run_iteration(l.state)
-                except Exception:  # noqa: BLE001 - serial path: any failure is S3
+                except DEVICE_ERRORS:
+                    raise
+                except Exception:  # noqa: BLE001 - serial path: the app's failure is S3
                     failed.append(l)
             return failed
         for l, s in zip(lanes, new_states):
@@ -823,7 +827,9 @@ class CrashTester:
         for i, (persisted, restart_iter) in enumerate(lanes):
             try:
                 state = self._restart_init_cached(persisted)
-            except Exception:  # noqa: BLE001 - serial path: any failure is S3
+            except DEVICE_ERRORS:
+                raise
+            except Exception:  # noqa: BLE001 - serial path: the app's failure is S3
                 out[i] = ("S3", 0, float("nan"))
                 continue
             lane = CrashTester._Lane(i, state, restart_iter)
@@ -980,6 +986,8 @@ class CrashTester:
             state = app.restart_init(self.seed, persisted)
             state, executed = app.run_to_completion(state, restart_iter, golden_iters)
             return self._finish_classify(state, restart_iter + executed)
+        except DEVICE_ERRORS:
+            raise
         except Exception:  # incl. FloatingPointError blow-ups
             return "S3", 0, float("nan")
 
@@ -1034,6 +1042,8 @@ class CrashTester:
                 state2, recrash_iter, self._golden_iters
             )
             return self._finish_classify(state2, recrash_iter + executed)
+        except DEVICE_ERRORS:
+            raise
         except Exception:  # incl. FloatingPointError blow-ups
             return "S3", 0, float("nan")
 

@@ -18,8 +18,15 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.errors import JaxRuntimeError
 
 State = Dict[str, np.ndarray]
+
+#: errors that are the device's, never an app's: an XLA runtime error (out
+#: of memory, a failed compile or launch).  Code that attributes an app's own
+#: exception to a crash test (S3) lets these through first, so a failing
+#: device stops the campaign instead of passing as a crash outcome.
+DEVICE_ERRORS = (JaxRuntimeError,)
 
 
 @dataclass(frozen=True)
@@ -205,6 +212,8 @@ class IterativeApp:
         for s, it in zip(states, its):
             try:
                 out.append(bool(self.converged(s, it)))
+            except DEVICE_ERRORS:
+                raise
             except Exception as e:  # noqa: BLE001 - captured per lane
                 out.append(e)
         return out
@@ -216,6 +225,8 @@ class IterativeApp:
         for s in states:
             try:
                 out.append(self.verify(s))
+            except DEVICE_ERRORS:
+                raise
             except Exception as e:  # noqa: BLE001 - captured per lane
                 out.append(e)
         return out

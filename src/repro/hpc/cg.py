@@ -40,6 +40,12 @@ def _ratio(num, den):
     return jnp.where(den != 0.0, num / den, 0.0)
 
 
+@jax.jit
+def _alpha(rho, p, q):
+    """The x-update's step ``rho / (p . q)`` in one dispatch."""
+    return _ratio(rho, _dot(p, q))
+
+
 # Batched lane hooks for the vectorized campaign engine.  CG is matrix-free
 # (the Laplacian is a stencil), so the whole iteration is elementwise chains
 # plus per-lane reductions over the *data* axis — no ``dot_general`` — and
@@ -61,9 +67,8 @@ def _cg_step_core(a: dict, b: jnp.ndarray, one: jnp.ndarray, g: int, rr_every: i
     """
     p, r, x = a["p"], a["r"], a["x"]
     q = jax.vmap(lambda v: laplacian_apply(v, g))(p)
-    pq = _dot(p, q)
     rho = a["rho"]
-    alpha = _ratio(rho, pq)
+    alpha = _alpha(rho, p, q)
     x = x + (alpha * p) * one
     kk = a["k"]
     use_rr = ((kk + 1) % rr_every) == 0 if rr_every else jnp.zeros_like(kk, bool)
@@ -139,8 +144,7 @@ class CGApp(IterativeApp):
 
     def _x_update(self, s: State) -> State:
         s = dict(s)
-        pq = _dot(jnp.asarray(s["p"]), jnp.asarray(s["q"]))
-        alpha = float(_ratio(jnp.asarray(s["rho"]), pq)[0])
+        alpha = float(_alpha(s["rho"], s["p"], s["q"])[0])
         s["alpha"] = np.array([alpha], np.float32)
         s["x"] = s["x"] + alpha * s["p"]
         return s

@@ -7,16 +7,19 @@ The backend is steered with ``monkeypatch``; everything runs on the CPU.
 """
 import jax
 import pytest
+from jax.errors import JaxRuntimeError
 
 from repro.compile_cache import DEFAULT_CACHE_DIR, enable_compile_cache
 from repro.core import CrashTester, PersistPlan
 from repro.core.crash_tester import campaign_executor
+from repro.core.faults import MultiCrash
 from repro.core.workflow import WorkflowConfig, run_workflow
 from repro.hpc.suite import ci_app, default_cache
 
 
 def _tiny(name="heat"):
-    app = ci_app(name, grid=12, n_iters=40)
+    # montecarlo has no grid, and its CI size is already small
+    app = ci_app(name) if name == "montecarlo" else ci_app(name, grid=12, n_iters=40)
     return app, default_cache(app)
 
 
@@ -70,20 +73,49 @@ def test_batched_step_failure_raises(monkeypatch):
         tester.run_campaign(6)
 
 
+def _device_error(*a, **k):
+    raise JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory allocating lane buffers")
+
+
+def _overflowing(states):
+    raise OverflowError("lane counter out of range")
+
+
 def test_batched_step_lane_arithmetic_error_stays_per_lane(monkeypatch):
     """A lane's own ArithmeticError is still attributed per lane: the batch
     falls back to serial steps and the records equal the ref oracle's."""
     from repro.core.crash_tester import records_match
 
     app, cache = _tiny("sor")
-
-    def overflowing(states):
-        raise OverflowError("lane counter out of range")
-
     ref = CrashTester(app, PersistPlan.none(), cache, seed=1, engine="ref").run_campaign(6)
-    monkeypatch.setattr(app, "run_iteration_batch", overflowing)
+    monkeypatch.setattr(app, "run_iteration_batch", _overflowing)
     vec = CrashTester(app, PersistPlan.none(), cache, seed=1, engine="vec").run_campaign(6)
     assert records_match(ref.records, vec.records)
+
+
+# Each case breaks one app hook after the golden run, so the error strikes
+# inside the recompute, where an app's own exception would classify as S3.
+@pytest.mark.parametrize("engine", ["ref", "vec"])
+@pytest.mark.parametrize("app_name,hook,setup,fault", [
+    pytest.param("heat", "init", {}, None, id="restart"),
+    pytest.param("montecarlo", "verify", {}, None, id="verify"),
+    pytest.param("montecarlo", "converged", {"supports_lane_driver": False}, None,
+                 id="converged"),
+    pytest.param("sor", "run_iteration", {"run_iteration_batch": _overflowing}, None,
+                 id="serial-step"),
+    pytest.param("heat", "run_iteration", {}, MultiCrash(), id="recovery-crash"),
+])
+def test_device_error_in_recompute_raises(app_name, hook, setup, fault, engine, monkeypatch):
+    """A device error is never a crash outcome: it stops the campaign on
+    both engines instead of being recorded as S3."""
+    app, cache = _tiny(app_name)
+    tester = CrashTester(app, PersistPlan.none(), cache, seed=1, engine=engine, fault=fault)
+    tester._ensure_golden()
+    monkeypatch.setattr(app, hook, _device_error)
+    for name, value in setup.items():
+        monkeypatch.setattr(app, name, value)
+    with pytest.raises(JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        tester.run_campaign(6)
 
 
 # -------------------------------------------------------- compile cache

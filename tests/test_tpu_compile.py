@@ -12,7 +12,7 @@ which is the CPU here; each test steers that choice to the chip's with
 ``monkeypatch`` and compiles a fresh ``jax.jit`` of the op's body.
 """
 import functools
-import os
+import importlib.util
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +28,17 @@ SERVE_CACHE_BYTES = 24 * 4 * 65 * 32 * 64 * 2
 
 @pytest.fixture(scope="module")
 def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    """The TPU library takes a lock on load that any other process holding
+    it would refuse; a described topology opens no chip, so this process
+    may load it alongside others (test workers compile side by side)."""
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler (libtpu) in this install")
     from jax.experimental import topologies
 
-    try:
-        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        yield topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 
 
 @pytest.fixture(scope="module")
