@@ -6,7 +6,7 @@ fails per its trace and recovers via the policy under test.  The EasyCrash
 policies draw recovery outcomes from a crash-campaign-*measured*
 :class:`~repro.core.sysim.RecomputeProfile` of the ``decode`` app (PR 6's
 registry model app) and pay a *measured* delta-flush overhead
-(``ManagerStats.bytes_written`` through
+(dirty blocks, ``ManagerStats.blocks_written``, through
 :func:`~repro.core.efficiency.persist_overhead_fraction`) against their
 serving rate; checkpoint policies pause serving for ``t_chk`` at the
 Young/stretched-Young interval and come back *cold* (every interrupted
@@ -81,8 +81,9 @@ def decode_profile(fast: bool = True):
         dt += time.perf_counter() - t0
         mgr.maybe_flush(step, {k: np.asarray(v) for k, v in s.items()})
     mgr.close()
+    # the arena keeps no files, so the traffic is its dirty blocks
     t_s = persist_overhead_fraction(
-        mgr.stats.bytes_written / n_steps, max(dt / n_steps, 1e-6)
+        mgr.stats.blocks_written * arena.block_bytes / n_steps, max(dt / n_steps, 1e-6)
     )
     _PROFILE_CACHE[fast] = (app, profile, t_s)
     return _PROFILE_CACHE[fast]

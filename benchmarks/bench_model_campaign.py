@@ -55,8 +55,8 @@ def _persist_traffic(app, n_steps: int = 6):
             dt += t.dt
             mgr.maybe_flush(step, {k: np.asarray(v) for k, v in s.items()})
         mgr.close()
-        # steady state: skip the first flush (cold arena = full write)
-        out[mode] = mgr.stats.bytes_written / n_steps
+        # the arena keeps no files, so the traffic is its dirty blocks
+        out[mode] = mgr.stats.blocks_written * arena.block_bytes / n_steps
         out["step_time"] = dt / n_steps
     return out
 

@@ -177,7 +177,8 @@ def phase_campaign():
 
 def phase_server():
     """Uninterrupted vs killed-and-resumed decode with delta persistence,
-    plus a whole-object-rewrite run for the bytes a full flush writes."""
+    plus a whole-object-rewrite run: the blocks each mode marks dirty and the
+    bytes the arena's files receive."""
     from repro.launch.serve import main as serve_main
 
     def serve(tag, *extra):
@@ -197,6 +198,8 @@ def phase_server():
         raise AssertionError("full-rewrite run decoded different tokens")
     print(f"[server] tokens_shape={list(ref['tokens'].shape)} "
           f"tokens_per_s={ref['tokens_per_s']:.3f} "
+          f"delta_blocks_written={ref['blocks_written']} "
+          f"full_rewrite_blocks_written={full['blocks_written']} "
           f"delta_bytes_written={ref['bytes_written']} "
           f"full_rewrite_bytes_written={full['bytes_written']} "
           f"resumed_at_step={half} resumed_tokens_equal=ok")

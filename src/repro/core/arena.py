@@ -21,6 +21,7 @@ from typing import Dict, Iterable, Mapping, Optional
 
 import numpy as np
 
+from ..telemetry import span
 from .blocks import DEFAULT_BLOCK_BYTES, block_diff_mask, mix_blocks, obj_num_blocks
 from .durable import durable_replace
 
@@ -62,6 +63,8 @@ class NVMArena:
         self.backing_dir = backing_dir
         self._store: Dict[str, np.ndarray] = {}
         self.stats = WriteStats()
+        #: bytes written to the objects' backing files, headers included
+        self.file_bytes = 0
         if backing_dir:
             os.makedirs(backing_dir, exist_ok=True)
 
@@ -143,7 +146,8 @@ class NVMArena:
         self.stats.flushed_clean_blocks += total - written
         self.stats.flush_ops += 1
         if written:
-            self._store[name] = mix_blocks(cur, live_value, mask, self.block_bytes)
+            with span("arena.mix", object=name):
+                self._store[name] = mix_blocks(cur, live_value, mask, self.block_bytes)
             self._persist_to_backing(name)
         return written
 
@@ -167,25 +171,32 @@ class NVMArena:
         path = self._backing_path(name)
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
-            np.save(f, self._store[name])
-            f.flush()
-            os.fsync(f.fileno())
-        durable_replace(tmp, path)
+            with span("arena.write", object=name) as s:
+                np.save(f, self._store[name])
+                f.flush()
+                nbytes = f.tell()
+                s.add(nbytes=nbytes)
+            with span("arena.fsync", object=name):
+                os.fsync(f.fileno())
+        with span("arena.rename", object=name):
+            durable_replace(tmp, path)
+        self.file_bytes += nbytes
 
     def save_manifest(self) -> None:
         if not self.backing_dir:
             return
-        manifest = {
-            "block_bytes": self.block_bytes,
-            "objects": {k: str(v.dtype) for k, v in self._store.items()},
-        }
-        path = os.path.join(self.backing_dir, "manifest.json")
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(manifest, f)
-            f.flush()
-            os.fsync(f.fileno())
-        durable_replace(tmp, path)
+        with span("arena.manifest"):
+            manifest = {
+                "block_bytes": self.block_bytes,
+                "objects": {k: str(v.dtype) for k, v in self._store.items()},
+            }
+            path = os.path.join(self.backing_dir, "manifest.json")
+            tmp = path + ".tmp"
+            with open(tmp, "w") as f:
+                json.dump(manifest, f)
+                f.flush()
+                os.fsync(f.fileno())
+            durable_replace(tmp, path)
 
     @classmethod
     def reattach(cls, backing_dir: str) -> "NVMArena":

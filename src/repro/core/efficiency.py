@@ -171,9 +171,10 @@ def persist_overhead_fraction(
 ) -> float:
     """Measured ``t_s``: fraction of wall time spent writing flush traffic.
 
-    Turns the *measured* delta-flush write volume (``ManagerStats.bytes_written``
-    per flush, which delta mode shrinks to the changed blocks only) into the
-    EasyCrash overhead knob that :func:`efficiency_with` taxes useful time by.
+    Turns the *measured* flush write volume (``ManagerStats.bytes_written``
+    per flush: the bytes the arena's backing files received, every object
+    with a dirty block rewritten whole) into the EasyCrash overhead knob that
+    :func:`efficiency_with` taxes useful time by.
     Clamped to 1.0 — a flush that cannot keep up with the interval saturates.
     """
     if flush_interval_s <= 0:

@@ -35,10 +35,11 @@ discrete-event simulation of a replica fleet:
 * **persistence cost** — the checkpointing policies pause serving for
   ``t_chk`` at the (Young/stretched-Young) interval between requests, and
   the EasyCrash policies inflate every service time by ``1 / (1 - t_s)``
-  where ``t_s`` is the measured delta-flush overhead
+  where ``t_s`` is the measured flush overhead
   (:func:`~repro.core.efficiency.persist_overhead_fraction` of
-  ``ManagerStats.bytes_written``) — persist traffic is charged against
-  serving capacity, per Huang et al.'s persistence-cost analysis.
+  ``ManagerStats.bytes_written``, the bytes the arena's files received) —
+  persist traffic is charged against serving capacity, per Huang et al.'s
+  persistence-cost analysis.
 
 **Warm vs cold** is the mechanism under study: a warm recovery resumes the
 preempted request with its remaining work and keeps the queue intact; a cold
@@ -176,11 +177,11 @@ class FleetConfig:
 
     ``t_s`` is the EasyCrash flush-overhead fraction charged against the
     serving rate of the ``easycrash``/``hybrid`` policies (measure it with
-    :func:`~repro.core.efficiency.persist_overhead_fraction` from delta-mode
-    ``bytes_written``); ``t_iter`` converts the profile's S2
-    extra-recompute-iteration draws into downtime seconds (a serving
-    "iteration" is one decode step, so it is orders of magnitude below the
-    HPC default).  ``interval`` overrides the Young/stretched-Young
+    :func:`~repro.core.efficiency.persist_overhead_fraction` from
+    ``bytes_written``, the bytes the arena's files received); ``t_iter``
+    converts the profile's S2 extra-recompute-iteration draws into downtime
+    seconds (a serving "iteration" is one decode step, so it is orders of
+    magnitude below the HPC default).  ``interval`` overrides the Young/stretched-Young
     checkpoint interval; ``None`` uses
     :func:`~repro.core.sysim.default_interval` at the replica trace's MTBF.
     """

@@ -27,7 +27,9 @@ from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..telemetry import span, tracing
 from .arena import NVMArena
+from .blocks import obj_num_blocks
 from .efficiency import young_interval
 
 
@@ -77,8 +79,10 @@ class FlushPolicy:
     ``"auto"`` (arena's own byte diff), ``"delta"`` (incremental: changed
     blocks only, detected by the ``delta_snapshot`` kernel) or ``"full"``
     (whole-object rewrite, the C/R-style baseline).  All three produce
-    byte-identical NVM images; they differ only in write traffic, which
-    ``ManagerStats.bytes_written`` measures.
+    byte-identical NVM images; they differ in the blocks they mark dirty,
+    which ``ManagerStats.blocks_written`` counts.  ``bytes_written`` counts
+    what the arena's backing files received: an object with any dirty block
+    is rewritten whole.
     """
 
     leaves: Tuple[str, ...]
@@ -98,7 +102,9 @@ class FlushPolicy:
 class ManagerStats:
     flushes_issued: int = 0
     flushes_skipped: int = 0
+    #: dirty blocks the flushes wrote into the arena's images
     blocks_written: int = 0
+    #: bytes the flushes wrote to the arena's backing files (0 without files)
     bytes_written: int = 0
     checkpoints_taken: int = 0
     easycrash_restores: int = 0
@@ -155,32 +161,46 @@ class EasyCrashManager:
         Returns True if a flush was issued (or enqueued)."""
         if step % self.policy.every_steps != 0:
             return False
-        flat = flatten_state(state)
-        sel = self._selected(flat)
-        sel["__step__"] = np.asarray(step, dtype=np.int64)
-        payload = {k: np.array(v, copy=True) for k, v in sel.items()}
-        if self.policy.async_flush:
-            if self._q.qsize() >= self.policy.max_pending:
-                self.stats.flushes_skipped += 1   # straggler mitigation: skip
-                return False
-            self._q.put((step, payload))
-        else:
-            self._flush_now(step, payload)
+        with span("flush", step=step, mode=self.policy.persist_mode):
+            with span("flush.stage") as stage:
+                flat = flatten_state(state)
+                sel = self._selected(flat)
+                sel["__step__"] = np.asarray(step, dtype=np.int64)
+                payload = {k: np.array(v, copy=True) for k, v in sel.items()}
+                if tracing():
+                    stage.add(nbytes=sum(v.nbytes for v in payload.values()))
+            if self.policy.async_flush:
+                if self._q.qsize() >= self.policy.max_pending:
+                    self.stats.flushes_skipped += 1   # straggler mitigation: skip
+                    return False
+                self._q.put((step, payload))
+            else:
+                self._flush_now(step, payload)
         self.stats.flushes_issued += 1
         return True
 
     def _flush_now(self, step: int, payload: Mapping[str, np.ndarray]) -> None:
         from .delta_persist import persist_mask_for
 
+        block = self.arena.block_bytes
+        file_bytes = self.arena.file_bytes
         for name, arr in payload.items():
-            mask = persist_mask_for(
-                self.policy.persist_mode, self.arena.peek(name), arr,
-                self.arena.block_bytes,
-            )
+            cur = self.arena.peek(name)
+            with span("flush.mask", object=name, nbytes=arr.nbytes,
+                      block_bytes=block) as s:
+                mask = persist_mask_for(self.policy.persist_mode, cur, arr, block)
+                if tracing():
+                    blocks = obj_num_blocks(arr, block)
+                    if mask is not None:
+                        s.add(blocks=blocks, dirty_blocks=int(np.count_nonzero(mask)))
+                    elif cur is None or cur.nbytes != arr.nbytes:
+                        s.add(blocks=blocks, dirty_blocks=blocks)  # first flush: all
+                    else:
+                        s.add(blocks=blocks)  # "auto": the arena diffs
             written = self.arena.flush(name, arr, dirty_resident_mask=mask)
             self.stats.blocks_written += written
-            self.stats.bytes_written += written * self.arena.block_bytes
         self.arena.save_manifest()
+        self.stats.bytes_written += self.arena.file_bytes - file_bytes
 
     def _drain(self) -> None:
         while True:

@@ -17,6 +17,7 @@ import argparse
 import os
 import tempfile
 import time
+import uuid
 from typing import Dict
 
 import jax
@@ -28,6 +29,7 @@ from ..configs import get_arch
 from ..core.arena import NVMArena
 from ..core.manager import EasyCrashManager, FlushPolicy, flatten_state
 from ..models import init_cache, init_params, scaled_down
+from ..telemetry import span, tracing
 from .steps import make_decode_fn, make_prefill_step
 
 
@@ -36,30 +38,38 @@ class SimulatedFailure(RuntimeError):
 
 
 def run(args) -> Dict[str, float]:
-    cfg = get_arch(args.arch)
-    if not args.full_size:
-        cfg = scaled_down(cfg, width=args.width)
-    key = jax.random.PRNGKey(args.seed)
-    params = init_params(cfg, key)
-    prefill_fn = jax.jit(make_prefill_step(cfg))
-    decode_fn = jax.jit(make_decode_fn(cfg), donate_argnums=(1,))
+    with span("serve.session", session=uuid.uuid4().hex, prompts=args.prompts,
+              prompt_len=args.prompt_len, decode_steps=args.decode_steps) as session:
+        return _serve(args, session)
 
-    os.makedirs(args.workdir, exist_ok=True)
-    arena_dir = os.path.join(args.workdir, "serve_arena")
-    try:
-        arena = NVMArena.reattach(arena_dir)
-        resumed = True
-    except Exception:
-        arena = NVMArena(backing_dir=arena_dir)
-        resumed = False
-    policy = FlushPolicy(leaves=("cache", "tokens"), every_steps=args.flush_every,
-                         async_flush=False, persist_mode=args.persist_mode)
-    mgr = EasyCrashManager(arena, policy)
 
-    max_len = args.prompt_len + args.decode_steps + 1
-    prompts = jax.random.randint(
-        jax.random.PRNGKey(7), (args.prompts, args.prompt_len), 0, cfg.vocab
-    )
+def _serve(args, session: span) -> Dict[str, float]:
+    with span("serve.setup"):
+        cfg = get_arch(args.arch)
+        if not args.full_size:
+            cfg = scaled_down(cfg, width=args.width)
+        key = jax.random.PRNGKey(args.seed)
+        params = init_params(cfg, key)
+        prefill_fn = jax.jit(make_prefill_step(cfg))
+        decode_fn = jax.jit(make_decode_fn(cfg), donate_argnums=(1,))
+
+        os.makedirs(args.workdir, exist_ok=True)
+        arena_dir = os.path.join(args.workdir, "serve_arena")
+        try:
+            arena = NVMArena.reattach(arena_dir)
+            resumed = True
+        except Exception:
+            arena = NVMArena(backing_dir=arena_dir)
+            resumed = False
+        session.add(resumed=resumed)
+        policy = FlushPolicy(leaves=("cache", "tokens"), every_steps=args.flush_every,
+                             async_flush=False, persist_mode=args.persist_mode)
+        mgr = EasyCrashManager(arena, policy)
+
+        max_len = args.prompt_len + args.decode_steps + 1
+        prompts = jax.random.randint(
+            jax.random.PRNGKey(7), (args.prompts, args.prompt_len), 0, cfg.vocab
+        )
 
     if resumed and "__step__" in arena:
         start = int(arena.get("__step__"))
@@ -73,21 +83,28 @@ def run(args) -> Dict[str, float]:
         token = all_tokens[-1][:, -1:]
     else:
         start = 0
-        logits, cache = prefill_fn(params, {"tokens": prompts})
-        # right-size the cache for continued decoding
-        full_cache = init_cache(cfg, args.prompts, max_len)
-        cache = _splice_cache(cfg, full_cache, cache, args.prompt_len)
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        all_tokens = [prompts, token]
+        with span("serve.prefill"):
+            logits, cache = prefill_fn(params, {"tokens": prompts})
+            # right-size the cache for continued decoding
+            full_cache = init_cache(cfg, args.prompts, max_len)
+            cache = _splice_cache(cfg, full_cache, cache, args.prompt_len)
+            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+            all_tokens = [prompts, token]
 
     t0 = time.time()
     for step in range(start, args.decode_steps):
-        token, cache = decode_fn(params, cache, token)
+        with span("serve.decode", step=step + 1):
+            token, cache = decode_fn(params, cache, token)
+            # the host copy below waits for this program anyway
+            token.block_until_ready()
         all_tokens.append(token)
-        host = {
-            "cache": jax.tree.map(np.asarray, cache),
-            "tokens": np.asarray(jnp.concatenate(all_tokens, axis=1)),
-        }
+        with span("serve.host_copy") as copy:
+            host = {
+                "cache": jax.tree.map(np.asarray, cache),
+                "tokens": np.asarray(jnp.concatenate(all_tokens, axis=1)),
+            }
+            if tracing():
+                copy.add(nbytes=sum(a.nbytes for a in jax.tree.leaves(host)))
         mgr.maybe_flush(step + 1, host)
         if args.inject_failure_at and step + 1 == args.inject_failure_at:
             raise SimulatedFailure(f"injected failure at decode step {step + 1}")
@@ -112,7 +129,7 @@ def fleet_report(stats: Dict[str, float], args) -> Dict[str, dict]:
 
     The single-process run measures the two quantities the fleet simulator
     needs from the real system: the per-step decode time (service rate) and
-    the delta-flush traffic (``bytes_written`` -> ``t_s`` via
+    the flush traffic to the arena's files (``bytes_written`` -> ``t_s`` via
     :func:`~repro.core.efficiency.persist_overhead_fraction`).  Everything
     else — arrivals, failures, recovery policy — is simulated, so the same
     binary answers "what would this server's goodput/p99 look like across N
