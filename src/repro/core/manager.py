@@ -155,11 +155,16 @@ class EasyCrashManager:
             if any(self._match(name, l) for l in self.policy.leaves)
         }
 
+    def due(self, step: int) -> bool:
+        """Whether the cadence flushes at ``step``: a caller can leave the
+        state on the device on the steps where it does not."""
+        return step % self.policy.every_steps == 0
+
     def maybe_flush(self, step: int, state: Mapping[str, Any]) -> bool:
         """Issue an EasyCrash persistence op if the cadence says so.
 
         Returns True if a flush was issued (or enqueued)."""
-        if step % self.policy.every_steps != 0:
+        if not self.due(step):
             return False
         with span("flush", step=step, mode=self.policy.persist_mode):
             with span("flush.stage") as stage:

@@ -95,21 +95,17 @@ def _serve(args, session: span) -> Dict[str, float]:
     for step in range(start, args.decode_steps):
         with span("serve.decode", step=step + 1):
             token, cache = decode_fn(params, cache, token)
-            # the host copy below waits for this program anyway
+            # the span holds the step's program, not only its dispatch
             token.block_until_ready()
         all_tokens.append(token)
-        with span("serve.host_copy") as copy:
-            host = {
-                "cache": jax.tree.map(np.asarray, cache),
-                "tokens": np.asarray(jnp.concatenate(all_tokens, axis=1)),
-            }
-            if tracing():
-                copy.add(nbytes=sum(a.nbytes for a in jax.tree.leaves(host)))
-        mgr.maybe_flush(step + 1, host)
+        # the state reaches the host only where a flush takes it, and before
+        # the next step donates the cache
+        if mgr.due(step + 1):
+            mgr.maybe_flush(step + 1, _to_host(all_tokens, cache))
         if args.inject_failure_at and step + 1 == args.inject_failure_at:
             raise SimulatedFailure(f"injected failure at decode step {step + 1}")
     dt = time.time() - t0
-    out = np.asarray(jnp.concatenate(all_tokens, axis=1))
+    out = _to_host(all_tokens)["tokens"]
     stats = {
         "decode_steps": args.decode_steps - start,
         "tokens_per_s": (args.decode_steps - start) * args.prompts / max(dt, 1e-9),
@@ -122,6 +118,18 @@ def _serve(args, session: span) -> Dict[str, float]:
     mgr.close()
     stats["tokens"] = out
     return stats
+
+
+def _to_host(all_tokens, cache=None) -> Dict[str, object]:
+    """The decode cache, if given, and the token buffer on the host. The
+    buffer's pieces are joined there: a join on the device would compile
+    anew for every length."""
+    with span("serve.host_copy") as copy:
+        host = {} if cache is None else {"cache": jax.tree.map(np.asarray, cache)}
+        host["tokens"] = np.concatenate(jax.device_get(all_tokens), axis=1)
+        if tracing():
+            copy.add(nbytes=sum(a.nbytes for a in jax.tree.leaves(host)))
+    return host
 
 
 def fleet_report(stats: Dict[str, float], args) -> Dict[str, dict]:

@@ -66,6 +66,16 @@ def test_flush_cadence():
     assert issued == [True, False, False, False, True, False, False, False]
 
 
+@pytest.mark.parametrize("every", [1, 3, 8])
+def test_due_is_where_maybe_flush_flushes(every):
+    mgr = EasyCrashManager(NVMArena(), FlushPolicy(leaves=("params",), every_steps=every,
+                                                   async_flush=False))
+    due = [mgr.due(s) for s in range(25)]
+    issued = [mgr.maybe_flush(s, _state(s)) for s in range(25)]
+    assert due == issued
+    assert mgr.stats.flushes_issued == sum(due) == len(range(0, 25, every))
+
+
 def test_async_flush_barrier(tmp_path):
     arena = NVMArena(backing_dir=str(tmp_path))
     policy = FlushPolicy(leaves=("params", "opt"), every_steps=1,

@@ -23,10 +23,10 @@ KV = ("cache/group0/pos0/k", "cache/group0/pos0/v")
 OBJECTS = (*KV, "cache/t", "tokens", "__step__")
 
 
-def _serve(workdir, mode="delta"):
+def _serve(workdir, mode="delta", every=EVERY):
     return serve.main(["--width", str(WIDTH), "--prompts", str(PROMPTS),
                        "--prompt-len", str(PROMPT_LEN), "--decode-steps", str(STEPS),
-                       "--flush-every", str(EVERY), "--persist-mode", mode,
+                       "--flush-every", str(every), "--persist-mode", mode,
                        "--workdir", str(workdir)])
 
 
@@ -51,7 +51,11 @@ def sessions(tmp_path_factory):
     out["traced"] = _serve(root / "traced")
     jax.profiler.stop_trace()
     out["full"] = _serve(root / "full", mode="full")
+    jax.profiler.start_trace(str(root / "trace_nopersist"))
+    _serve(root / "nopersist", every=10 ** 9)
+    jax.profiler.stop_trace()
     out["spans"] = _read_spans(str(root / "trace"))
+    out["nopersist_spans"] = _read_spans(str(root / "trace_nopersist"))
     out["root"] = root
     return out
 
@@ -84,7 +88,8 @@ def _files_received(arena_dir):
 
 @pytest.mark.parametrize("name,count", [
     ("serve.session", 1), ("serve.setup", 1), ("serve.prefill", 1),
-    ("serve.decode", STEPS), ("serve.host_copy", STEPS),
+    # a copy for each flush, and the served token buffer at the end
+    ("serve.decode", STEPS), ("serve.host_copy", FLUSHES + 1),
     ("flush", FLUSHES), ("flush.stage", FLUSHES),
     ("flush.mask", FLUSHES * len(OBJECTS)), ("arena.write", FLUSHES * len(OBJECTS)),
     ("arena.fsync", FLUSHES * len(OBJECTS)), ("arena.rename", FLUSHES * len(OBJECTS)),
@@ -107,7 +112,20 @@ def test_spans_carry_their_stats(sessions):
     cache = sum(a.nbytes for a in jax.tree.leaves(
         init_cache(_cfg(), PROMPTS, PROMPT_LEN + STEPS + 1)))
     copies = [c[4]["nbytes"] for c in _named(sessions, "serve.host_copy")]
-    assert copies == [cache + 4 * PROMPTS * (PROMPT_LEN + 1 + k) for k in range(1, STEPS + 1)]
+    assert copies == [cache + _token_bytes(EVERY * k) for k in range(1, FLUSHES + 1)] + [
+        _token_bytes(STEPS)]
+
+
+def _token_bytes(steps):
+    return 4 * PROMPTS * (PROMPT_LEN + 1 + steps)
+
+
+def test_a_session_without_flushes_copies_only_the_served_tokens(sessions):
+    spans = sessions["nopersist_spans"]
+    assert [s[4] for s in spans if s[0] == "serve.host_copy"] == [
+        {"nbytes": _token_bytes(STEPS)}]
+    assert sum(s[0] == "serve.decode" for s in spans) == STEPS
+    assert not any(s[0].startswith(("flush", "arena.")) for s in spans)
 
 
 @pytest.mark.parametrize("obj", OBJECTS)
