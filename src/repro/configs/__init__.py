@@ -5,6 +5,7 @@ from ..models.config import ModelConfig
 from .musicgen_medium import CONFIG as musicgen_medium
 from .minitron_8b import CONFIG as minitron_8b
 from .granite_8b import CONFIG as granite_8b
+from .granite_4_0_h_micro import CONFIG as granite_4_0_h_micro
 from .stablelm_1_6b import CONFIG as stablelm_1_6b
 from .nemotron_4_340b import CONFIG as nemotron_4_340b
 from .recurrentgemma_9b import CONFIG as recurrentgemma_9b
@@ -26,6 +27,7 @@ ARCHS: Dict[str, ModelConfig] = {
         llama4_scout_17b_a16e,
         qwen2_moe_a2_7b,
         internvl2_76b,
+        granite_4_0_h_micro,
     )
 }
 

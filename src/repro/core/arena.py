@@ -127,16 +127,9 @@ class NVMArena:
         """
         live_value = np.asarray(live_value)
         cur = self._store.get(name)
-        if cur is not None and cur.nbytes != live_value.nbytes:
-            cur = None  # object was reallocated/grown: full rewrite
-        if cur is None:
-            # first flush: everything is logically dirty
-            nb = obj_num_blocks(live_value, self.block_bytes)
-            self._store[name] = np.array(live_value, copy=True)
-            self.stats.flush_writes += nb
-            self.stats.flush_ops += 1
-            self._persist_to_backing(name)
-            return nb
+        if cur is None or cur.nbytes != live_value.nbytes:
+            # first flush, or the object was reallocated/grown
+            return self.rewrite(name, live_value)
         if dirty_resident_mask is None:
             dirty_resident_mask = block_diff_mask(cur, live_value, self.block_bytes)
         mask = np.asarray(dirty_resident_mask, dtype=bool)
@@ -150,6 +143,18 @@ class NVMArena:
                 self._store[name] = mix_blocks(cur, live_value, mask, self.block_bytes)
             self._persist_to_backing(name)
         return written
+
+    def rewrite(self, name: str, live_value: np.ndarray) -> int:
+        """Persistence operation that writes every block of the object: for
+        a first flush, and for an object that each step rewrites whole, where
+        a diff or mask would find every block dirty. Returns the blocks
+        written."""
+        nb = obj_num_blocks(live_value, self.block_bytes)
+        self._store[name] = np.array(live_value, copy=True)
+        self.stats.flush_writes += nb
+        self.stats.flush_ops += 1
+        self._persist_to_backing(name)
+        return nb
 
     def checkpoint_copy(self, name: str, value: np.ndarray) -> None:
         """Traditional C/R data copy: every block of the object is written."""

@@ -8,7 +8,9 @@ This is the framework-facing layer: given a train-state pytree and a
   step, and a skipped flush only increases staleness, which EasyCrash
   tolerates by construction);
 * performs delta flushes: only blocks that changed since the last flush
-  move, as flagged by the ``delta_snapshot`` Pallas kernel;
+  move, as flagged by the ``delta_snapshot`` Pallas kernel; objects that
+  every step rewrites whole (a model's recurrent state, named by the
+  state's layout, not by a user) are written whole with no mask;
 * takes full coordinated checkpoints at the Young interval stretched by the
   measured recomputability (MTBF' = MTBF / (1 - R));
 * on restart, tries the EasyCrash path (arena image + acceptance
@@ -122,9 +124,15 @@ class EasyCrashManager:
         t_chk: Optional[float] = None,
         recomputability: float = 0.0,
         step_time: float = 1.0,
+        rewritten: Sequence[str] = (),
     ):
+        """``rewritten``: state leaves (flat names, matched as
+        ``FlushPolicy.leaves`` are) that every step rewrites whole, as the
+        state's layout says; a flush writes them whole with no mask, whatever
+        ``persist_mode`` says of the rest."""
         self.arena = arena
         self.policy = policy
+        self.rewritten = tuple(rewritten)
         self.checkpoint_save = checkpoint_save
         self.checkpoint_restore = checkpoint_restore
         self.stats = ManagerStats()
@@ -190,6 +198,11 @@ class EasyCrashManager:
         block = self.arena.block_bytes
         file_bytes = self.arena.file_bytes
         for name, arr in payload.items():
+            if any(self._match(name, leaf) for leaf in self.rewritten):
+                with span("flush.whole", object=name, nbytes=arr.nbytes,
+                          blocks=obj_num_blocks(arr, block)):
+                    self.stats.blocks_written += self.arena.rewrite(name, arr)
+                continue
             cur = self.arena.peek(name)
             with span("flush.mask", object=name, nbytes=arr.nbytes,
                       block_bytes=block) as s:

@@ -206,6 +206,13 @@ def param_counts(cfg) -> Dict[str, float]:
             elif kind == "rwkv":
                 lora = max(32, d // 32)
                 mix = 5 * d * d + d * lora + lora * d
+            elif kind == "mamba":
+                m = cfg.mamba
+                inner = m.expand * d
+                heads = inner // m.head_dim
+                conv_ch = inner + 2 * m.n_groups * m.d_state
+                mix = (d * (inner + conv_ch + heads) + (m.d_conv + 1) * conv_ch
+                       + 3 * heads + inner + inner * d)
             else:
                 mix = 0.0
             if cfg.moe is not None and kind == "attn":

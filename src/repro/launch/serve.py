@@ -28,7 +28,7 @@ from ..compile_cache import enable_compile_cache
 from ..configs import get_arch
 from ..core.arena import NVMArena
 from ..core.manager import EasyCrashManager, FlushPolicy, flatten_state
-from ..models import init_cache, init_params, scaled_down
+from ..models import init_cache, init_params, rewritten_leaves, scaled_down
 from ..telemetry import span, tracing
 from .steps import make_decode_fn, make_prefill_step
 
@@ -64,7 +64,8 @@ def _serve(args, session: span) -> Dict[str, float]:
         session.add(resumed=resumed)
         policy = FlushPolicy(leaves=("cache", "tokens"), every_steps=args.flush_every,
                              async_flush=False, persist_mode=args.persist_mode)
-        mgr = EasyCrashManager(arena, policy)
+        rewritten = tuple(f"cache/{leaf}" for leaf in rewritten_leaves(cfg))
+        mgr = EasyCrashManager(arena, policy, rewritten=rewritten)
 
         max_len = args.prompt_len + args.decode_steps + 1
         prompts = jax.random.randint(
@@ -90,6 +91,7 @@ def _serve(args, session: span) -> Dict[str, float]:
             cache = _splice_cache(cfg, full_cache, cache, args.prompt_len)
             token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             all_tokens = [prompts, token]
+    session.add(**_cache_bytes(cache, rewritten))
 
     t0 = time.time()
     for step in range(start, args.decode_steps):
@@ -130,6 +132,19 @@ def _to_host(all_tokens, cache=None) -> Dict[str, object]:
         if tracing():
             copy.add(nbytes=sum(a.nbytes for a in jax.tree.leaves(host)))
     return host
+
+
+def _cache_bytes(cache, rewritten) -> Dict[str, int]:
+    """The decode cache's bytes of recurrent state (the subtrees a step
+    rewrites whole) and of attention K/V (the rest, but the counter)."""
+    out = {"state_bytes": 0, "kv_bytes": 0}
+    for g, layers in cache.items():
+        if g == "t":
+            continue
+        for pos, leaves in layers.items():
+            kind = "state_bytes" if f"cache/{g}/{pos}" in rewritten else "kv_bytes"
+            out[kind] += sum(a.nbytes for a in jax.tree.leaves(leaves))
+    return out
 
 
 def fleet_report(stats: Dict[str, float], args) -> Dict[str, dict]:

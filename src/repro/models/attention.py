@@ -3,7 +3,8 @@
 Sharding: query heads go to "heads" (model axis); K/V projections replicate
 when n_kv_heads doesn't divide the TP degree (the GQA<TP case) and the decode
 KV cache is then sequence-sharded ("kv_seq") instead of head-sharded.
-Supports causal and local-window (RecurrentGemma) masking.
+Supports causal and local-window (RecurrentGemma) masking, rotary or no
+position embedding (``cfg.rope``), and a configured score scale.
 
 The full-sequence path can route through the Pallas flash-attention kernel
 (``impl="pallas"``) on TPU; the einsum reference is the default and the
@@ -46,6 +47,12 @@ def attn_specs(cfg: ModelConfig, tp: int = 16) -> Dict:
     }
 
 
+def score_scale(cfg: ModelConfig) -> float:
+    """What attention scores are multiplied by: the configuration's
+    ``attn_scale``, else ``head_dim ** -0.5``."""
+    return cfg.attn_scale if cfg.attn_scale is not None else cfg.head_dim ** -0.5
+
+
 def _split_heads(x: jax.Array, n_heads: int, d_head: int) -> jax.Array:
     b, s, _ = x.shape
     return x.reshape(b, s, n_heads, d_head)
@@ -83,12 +90,16 @@ def attention_full(
     q = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wq"]), hq, dh)
     k = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wk"]), hkv, dh)
     v = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wv"]), hkv, dh)
-    cos, sin = rope_angles(positions, dh, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        cos, sin = rope_angles(positions, dh, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     q = with_logical(q, "batch", None, "heads", None)
     k = with_logical(k, "batch", None, "kv_heads" if hkv % 8 == 0 else None, None)
 
+    if impl != "reference" and cfg.attn_scale is not None:
+        # the kernels scale by head_dim ** -0.5; fold the difference into q
+        q = q * jnp.asarray(cfg.attn_scale * dh ** 0.5, q.dtype)
     if impl == "pallas":
         from ..kernels.flash_attention.ops import flash_attention
 
@@ -100,8 +111,7 @@ def attention_full(
     else:
         k = _repeat_kv(k, hq // hkv)
         v = _repeat_kv(v, hq // hkv)
-        scale = dh ** -0.5
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * score_scale(cfg)
         bias = _mask_bias(q.shape[1], k.shape[1], 0, window, jnp.float32)
         probs = jax.nn.softmax(scores.astype(jnp.float32) + bias, axis=-1).astype(q.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -200,9 +210,10 @@ def attention_decode(
     q = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wq"]), hq, dh)
     k = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wk"]), hkv, dh)
     v = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wv"]), hkv, dh)
-    cos, sin = rope_angles(t[None], dh, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        cos, sin = rope_angles(t[None], dh, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     slot = (t % length) if window else t
     cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k, slot, axis=1)
@@ -210,8 +221,7 @@ def attention_decode(
 
     kk = _repeat_kv(cache_k, hq // hkv)
     vv = _repeat_kv(cache_v, hq // hkv)
-    scale = dh ** -0.5
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale  # (B, H, 1, L)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * score_scale(cfg)  # (B, H, 1, L)
     kpos = jnp.arange(length)
     if window:
         valid = (kpos <= t % length) | (t >= length)  # rolling buffer: all valid once full

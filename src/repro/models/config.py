@@ -1,7 +1,7 @@
 """Model / shape configuration for the architecture zoo.
 
-One :class:`ModelConfig` describes any of the ten assigned architectures
-(dense GQA, MoE, SSM/RWKV-6, RG-LRU hybrid, audio/VLM backbones).  Layer
+One :class:`ModelConfig` describes any of the assigned architectures
+(dense GQA, MoE, SSM/RWKV-6, RG-LRU and Mamba-2 hybrids, audio/VLM backbones).  Layer
 stacks are described as *groups* — ``(pattern, repeat)`` pairs — so hybrids
 like RecurrentGemma's (rec, rec, attn) x 12 + (rec, rec) compile as one
 ``lax.scan`` per group.
@@ -12,7 +12,7 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Literal, Optional, Sequence, Tuple
 
-LayerKind = Literal["attn", "rec", "rwkv"]
+LayerKind = Literal["attn", "rec", "rwkv", "mamba"]
 
 
 @dataclass(frozen=True)
@@ -47,6 +47,21 @@ class RWKVConfig:
 
 
 @dataclass(frozen=True)
+class MambaConfig:
+    """Mamba-2 mixer: ``expand * d_model`` inner channels in heads of
+    ``head_dim``, a ``d_state``-wide SSM state per head, ``n_groups`` shared
+    B/C groups, a depthwise causal conv of width ``d_conv`` (with bias) and
+    the chunk length of the full-sequence SSD."""
+
+    d_state: int = 128
+    d_conv: int = 4
+    expand: int = 2
+    head_dim: int = 64
+    n_groups: int = 1
+    chunk: int = 256
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: Literal["dense", "moe", "ssm", "hybrid", "vlm", "audio"]
@@ -64,6 +79,7 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     rec: Optional[RecurrentConfig] = None
     rwkv: Optional[RWKVConfig] = None
+    mamba: Optional[MambaConfig] = None
     #: layer groups: ((kind, kind, ...), repeat); default = all-attn
     layer_groups: Optional[Tuple[Tuple[Tuple[str, ...], int], ...]] = None
     #: number of prepended frontend embeddings (VLM patches); 0 = none
@@ -76,6 +92,18 @@ class ModelConfig:
     remat: bool = True
     #: microbatches for gradient accumulation (1 = none)
     grad_accum: int = 1
+    #: rotary position embedding in attention; False = none (NoPE)
+    rope: bool = True
+    #: attention score scale; None = head_dim ** -0.5
+    attn_scale: Optional[float] = None
+    #: Granite's scalars: embeddings times ``embedding_multiplier``, each
+    #: sublayer's output times ``residual_multiplier`` before its residual
+    #: add, logits divided by ``logits_scaling`` (1 = absent)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    #: standard deviation of the embedding table's random draw
+    embed_std: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -109,6 +137,9 @@ class ModelConfig:
             assert self.rec is not None and self.rec.d_rnn > 0
         if "rwkv" in kinds:
             assert self.rwkv is not None
+        if "mamba" in kinds:
+            assert self.mamba is not None
+            assert self.mamba.expand * self.d_model % self.mamba.head_dim == 0
         return self
 
 
@@ -164,6 +195,8 @@ def scaled_down(cfg: ModelConfig, layers: int = 2, width: int = 64) -> ModelConf
         )
     rec = dataclasses.replace(cfg.rec, d_rnn=width, window=32) if cfg.rec else None
     rwkv = dataclasses.replace(cfg.rwkv, head_dim=16) if cfg.rwkv else None
+    mamba = (dataclasses.replace(cfg.mamba, d_state=16, head_dim=16, chunk=8)
+             if cfg.mamba else None)
     return dataclasses.replace(
         cfg,
         n_layers=layers,
@@ -176,6 +209,7 @@ def scaled_down(cfg: ModelConfig, layers: int = 2, width: int = 64) -> ModelConf
         moe=moe,
         rec=rec,
         rwkv=rwkv,
+        mamba=mamba,
         layer_groups=groups,
         frontend_tokens=min(cfg.frontend_tokens, 4),
         attn_window=min(cfg.attn_window, 32) if cfg.attn_window else None,

@@ -1,4 +1,4 @@
-"""LM assembly for all ten architectures: embed -> layer-group scans -> logits.
+"""LM assembly for the architecture zoo: embed -> layer-group scans -> logits.
 
 Layer stacks compile as one ``lax.scan`` per *group* (a repeated pattern of
 layer kinds) with rematerialization, so the HLO stays one-layer-sized even
@@ -8,10 +8,15 @@ for 96-layer models and the dry-run compiles quickly.  Per layer kind:
   rec   — RG-LRU recurrence + gated MLP
   rwkv  — RWKV-6 time-mix + gated MLP (channel-mix swapped for SwiGLU of the
           same width; parameter-count equivalent — noted in DESIGN.md)
+  mamba — Mamba-2 mixer (chunked SSD) + gated MLP
+
+Granite's scalars (``embedding_multiplier``, ``residual_multiplier``,
+``logits_scaling``) apply where the configuration sets them; at 1 they add
+no operation.
 
 Entry points: ``init_params`` / ``param_specs`` / ``forward`` /
 ``loss_and_aux`` / ``prefill`` / ``init_cache`` / ``cache_specs`` /
-``decode_step``.
+``decode_step`` / ``rewritten_leaves``.
 """
 from __future__ import annotations
 
@@ -31,6 +36,14 @@ from .attention import (
 )
 from .config import ModelConfig
 from .layers import dtype_of, mlp_apply, mlp_params, mlp_specs, normal_init, rms_norm
+from .mamba2 import (
+    mamba_decode_step,
+    mamba_full,
+    mamba_init_state,
+    mamba_params,
+    mamba_specs,
+    mamba_state_specs,
+)
 from .moe import moe_apply, moe_params, moe_specs
 from .rglru import (
     rglru_decode_step,
@@ -70,6 +83,8 @@ def _sublayer_params(cfg: ModelConfig, kind: str, key, n: int) -> Dict:
         p["rec"] = rglru_params(cfg, k_mix, n)
     elif kind == "rwkv":
         p["rwkv"] = rwkv_params(cfg, k_mix, n)
+    elif kind == "mamba":
+        p["mamba"] = mamba_params(cfg, k_mix, n)
     else:
         raise ValueError(kind)
     if _layer_uses_moe(cfg, kind):
@@ -87,6 +102,8 @@ def _sublayer_specs(cfg: ModelConfig, kind: str, tp: int) -> Dict:
         p["rec"] = rglru_specs()
     elif kind == "rwkv":
         p["rwkv"] = rwkv_specs()
+    elif kind == "mamba":
+        p["mamba"] = mamba_specs()
     if _layer_uses_moe(cfg, kind):
         p["moe"] = moe_specs(cfg)
     else:
@@ -98,7 +115,7 @@ def init_params(cfg: ModelConfig, key) -> Params:
     dt = dtype_of(cfg)
     keys = jax.random.split(key, 3 + len(cfg.groups))
     params: Params = {
-        "embed": normal_init(keys[0], (cfg.vocab, cfg.d_model), 1.0, dt),
+        "embed": normal_init(keys[0], (cfg.vocab, cfg.d_model), cfg.embed_std, dt),
         "final_norm": jnp.zeros((cfg.d_model,), dt),
     }
     if not cfg.tie_embeddings:
@@ -130,6 +147,12 @@ def param_specs(cfg: ModelConfig, tp: int = 16) -> Params:
 
 
 # ------------------------------------------------------------------ forward
+def _residual(cfg: ModelConfig, x: jax.Array, y: jax.Array) -> jax.Array:
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
 def _apply_sublayer(
     cfg: ModelConfig, kind: str, lp: Dict, x: jax.Array, positions: jax.Array,
     impl: str,
@@ -142,13 +165,15 @@ def _apply_sublayer(
         h = rglru_full(lp["rec"], h, cfg, impl=impl)
     elif kind == "rwkv":
         h = rwkv_scan_full(lp["rwkv"], h, cfg, impl=impl)
-    x = x + h
+    elif kind == "mamba":
+        h, _ = mamba_full(lp["mamba"], h, cfg)
+    x = _residual(cfg, x, h)
     h = rms_norm(x, lp["norm2"], cfg.norm_eps)
     if "moe" in lp:
         h, aux = moe_apply(lp["moe"], h, cfg)
     else:
         h = mlp_apply(lp["mlp"], h, cfg)
-    return x + h, aux
+    return _residual(cfg, x, h), aux
 
 
 def _run_groups(
@@ -171,9 +196,16 @@ def _run_groups(
     return x, aux_total
 
 
+def _embed_tokens(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Array:
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
 def _embed(cfg: ModelConfig, params: Params, tokens: jax.Array,
            patches: Optional[jax.Array]) -> jax.Array:
-    x = jnp.take(params["embed"], tokens, axis=0)
+    x = _embed_tokens(cfg, params, tokens)
     if patches is not None:
         x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
     return with_logical(x, "batch", "seq", None)
@@ -185,6 +217,8 @@ def _logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
         logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return with_logical(logits, "batch", None, "vocab")
 
 
@@ -233,8 +267,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
                 g[f"pos{pi}"] = rglru_init_state(cfg, rep, batch)
             elif kind == "rwkv":
                 g[f"pos{pi}"] = rwkv_init_state(cfg, rep, batch)
+            elif kind == "mamba":
+                g[f"pos{pi}"] = mamba_init_state(cfg, rep, batch)
         cache[f"group{gi}"] = g
     return cache
+
+
+def rewritten_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The decode cache's subtrees (``group<g>/pos<p>``) that every decode
+    step rewrites whole: the recurrent state of each non-attention layer.
+    Attention K/V gain one position a step, and ``t`` is a counter."""
+    return tuple(f"group{gi}/pos{pi}" for gi, (pattern, _) in enumerate(cfg.groups)
+                 for pi, kind in enumerate(pattern) if kind != "attn")
 
 
 def cache_specs(cfg: ModelConfig, tp: int = 16) -> Dict:
@@ -248,6 +292,8 @@ def cache_specs(cfg: ModelConfig, tp: int = 16) -> Dict:
                 g[f"pos{pi}"] = rglru_state_specs()
             elif kind == "rwkv":
                 g[f"pos{pi}"] = rwkv_state_specs()
+            elif kind == "mamba":
+                g[f"pos{pi}"] = mamba_state_specs()
         specs[f"group{gi}"] = g
     return specs
 
@@ -257,7 +303,7 @@ def decode_step(
 ) -> Tuple[jax.Array, Dict]:
     """token: (B, 1) int32.  Returns (logits (B, 1, V), updated cache)."""
     t = cache["t"]
-    x = jnp.take(params["embed"], token, axis=0)
+    x = _embed_tokens(cfg, params, token)
     x = with_logical(x, "batch", None, None)
     new_cache: Dict[str, Any] = {"t": t + 1}
 
@@ -283,13 +329,16 @@ def decode_step(
                 elif kind == "rwkv":
                     y, S, x_last = rwkv_decode_step(lp["rwkv"], hin, lc["S"], lc["x_last"], cfg)
                     new_layer_cache[f"pos{pi}"] = {"S": S, "x_last": x_last}
-                h = h + y
+                elif kind == "mamba":
+                    y, ssm, conv = mamba_decode_step(lp["mamba"], hin, lc["ssm"], lc["conv"], cfg)
+                    new_layer_cache[f"pos{pi}"] = {"ssm": ssm, "conv": conv}
+                h = _residual(cfg, h, y)
                 hin = rms_norm(h, lp["norm2"], cfg.norm_eps)
                 if "moe" in lp:
                     y, _ = moe_apply(lp["moe"], hin, cfg, decode=True)
                 else:
                     y = mlp_apply(lp["mlp"], hin, cfg)
-                h = h + y
+                h = _residual(cfg, h, y)
             return h, new_layer_cache
 
         x, new_gcache = jax.lax.scan(body, x, (gparams, gcache))
@@ -333,13 +382,15 @@ def prefill(
                 elif kind == "rwkv":
                     y = rwkv_scan_full(lp["rwkv"], hin, cfg, impl=impl)
                     new_layer_cache[f"pos{pi}"] = _rwkv_state_after(cfg, lp["rwkv"], hin)
-                h = h + y
+                elif kind == "mamba":
+                    y, new_layer_cache[f"pos{pi}"] = mamba_full(lp["mamba"], hin, cfg)
+                h = _residual(cfg, h, y)
                 hin = rms_norm(h, lp["norm2"], cfg.norm_eps)
                 if "moe" in lp:
                     y, _ = moe_apply(lp["moe"], hin, cfg)
                 else:
                     y = mlp_apply(lp["mlp"], hin, cfg)
-                h = h + y
+                h = _residual(cfg, h, y)
             return h, new_layer_cache
 
         x, gcache = jax.lax.scan(body, x, gparams)
@@ -355,8 +406,9 @@ def _kv_for_cache(cfg: ModelConfig, p: Dict, x: jax.Array, positions: jax.Array)
     hkv, dh = cfg.n_kv_heads, cfg.head_dim
     k = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wk"]), hkv, dh)
     v = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wv"]), hkv, dh)
-    cos, sin = rope_angles(positions, dh, cfg.rope_theta)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        cos, sin = rope_angles(positions, dh, cfg.rope_theta)
+        k = apply_rope(k, cos, sin)
     if cfg.attn_window:
         k = k[:, -cfg.attn_window:]
         v = v[:, -cfg.attn_window:]

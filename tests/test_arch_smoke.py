@@ -78,11 +78,12 @@ def test_decode_step_shapes(name):
 
 
 def test_all_archs_registered():
-    assert len(ALL) == 10
+    assert len(ALL) == 11
     assert set(ALL) == {
         "musicgen-medium", "minitron-8b", "granite-8b", "stablelm-1.6b",
         "nemotron-4-340b", "recurrentgemma-9b", "rwkv6-3b",
         "llama4-scout-17b-a16e", "qwen2-moe-a2.7b", "internvl2-76b",
+        "granite-4.0-h-micro",
     }
 
 
@@ -99,6 +100,7 @@ def test_exact_assigned_configs():
         "llama4-scout-17b-a16e": (48, 5120, 40, 8, 8192, 202048),
         "qwen2-moe-a2.7b": (24, 2048, 16, 16, 1408, 151936),
         "internvl2-76b": (80, 8192, 64, 8, 28672, 128256),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }
     for name, (L, d, hq, hkv, ff, V) in expect.items():
         cfg = get_arch(name)
@@ -114,3 +116,9 @@ def test_exact_assigned_configs():
     assert l4.num_experts == 16 and l4.top_k == 1
     rg = get_arch("recurrentgemma-9b")
     assert rg.total_layers() == 38 and rg.attn_window == 2048
+    gr = get_arch("granite-4.0-h-micro")
+    kinds = [k for pattern, rep in gr.groups for _ in range(rep) for k in pattern]
+    assert [i for i, k in enumerate(kinds) if k == "attn"] == [5, 15, 25, 35]
+    assert (gr.mamba.d_state, gr.mamba.head_dim, gr.mamba.expand, gr.mamba.chunk) == (128, 64, 2, 256)
+    assert not gr.rope and gr.attn_scale == 1 / 64 and gr.tie_embeddings
+    assert (gr.embedding_multiplier, gr.residual_multiplier, gr.logits_scaling) == (12, 0.22, 8)

@@ -54,3 +54,18 @@ def test_grouped_moe_matches_ungrouped():
     np.testing.assert_allclose(
         np.asarray(y1, np.float32), np.asarray(y2, np.float32), atol=1e-2
     )
+
+
+def test_chunked_attention_keeps_a_configured_score_scale():
+    """Granite's attention (no rotary, scores times 1/64) is the same through
+    the chunked path, which scales by head_dim ** -0.5 itself."""
+    from repro.models.attention import attention_full, attn_params
+
+    cfg = dataclasses.replace(scaled_down(get_arch("granite-4.0-h-micro")), dtype="float32")
+    assert not cfg.rope and cfg.attn_scale == 1 / 64
+    p = jax.tree.map(lambda a: a[0], attn_params(cfg, jax.random.PRNGKey(4), 1))
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.d_model))
+    positions = jnp.arange(32)
+    want = attention_full(p, x, cfg, positions, impl="reference")
+    got = attention_full(p, x, cfg, positions, impl="chunked")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
