@@ -7,8 +7,8 @@ live here:
 * value storage — one numpy array per named data object (the "NVM image"),
   optionally backed by memory-mapped files so a killed process can reattach
   (the memory-mapped-file offset mechanism the paper describes);
-* write accounting — every block written back (by eviction, by an explicit
-  flush, or by a checkpoint copy) is counted, reproducing the paper's Fig 9
+* write accounting — every block written back (by an explicit flush or by
+  a checkpoint copy) is counted, reproducing the paper's Fig 9
   endurance comparison.  Flushing a clean or non-resident block costs no
   NVM write, which is the asymmetry EasyCrash exploits.
 """
@@ -22,7 +22,12 @@ from typing import Dict, Iterable, Mapping, Optional
 import numpy as np
 
 from ..telemetry import span
-from .blocks import DEFAULT_BLOCK_BYTES, block_diff_mask, mix_blocks, obj_num_blocks
+from .blocks import (
+    DEFAULT_BLOCK_BYTES,
+    block_diff_mask,
+    mix_blocks_into,
+    obj_num_blocks,
+)
 from .durable import durable_replace
 
 
@@ -30,7 +35,6 @@ from .durable import durable_replace
 class WriteStats:
     """NVM write counters, in units of blocks."""
 
-    eviction_writes: int = 0     # natural write-backs from the (emulated) cache
     flush_writes: int = 0        # EasyCrash persistence operations
     checkpoint_writes: int = 0   # C/R data copies
     flush_ops: int = 0           # number of persistence operations issued
@@ -38,11 +42,10 @@ class WriteStats:
 
     @property
     def total(self) -> int:
-        return self.eviction_writes + self.flush_writes + self.checkpoint_writes
+        return self.flush_writes + self.checkpoint_writes
 
     def as_dict(self) -> Dict[str, int]:
         return {
-            "eviction_writes": self.eviction_writes,
             "flush_writes": self.flush_writes,
             "checkpoint_writes": self.checkpoint_writes,
             "flush_ops": self.flush_ops,
@@ -52,7 +55,11 @@ class WriteStats:
 
 
 class NVMArena:
-    """Persistent store for named data objects at block granularity."""
+    """Persistent store for named data objects at block granularity.
+
+    Every image the arena holds is its own row-major (C-contiguous), writable
+    array, so a masked flush merges its dirty blocks into it in place.
+    """
 
     def __init__(
         self,
@@ -82,7 +89,8 @@ class NVMArena:
     def peek(self, name: str) -> Optional[np.ndarray]:
         """No-copy view of the current NVM image (delta-mask computation).
 
-        Callers must not mutate the result; ``None`` if never persisted.
+        Callers must not mutate the result, and a later flush of the object
+        may update it in place; ``None`` if never persisted.
         """
         return self._store.get(name)
 
@@ -91,24 +99,13 @@ class NVMArena:
 
     def install(self, name: str, value: np.ndarray, count_writes: bool = False) -> None:
         """Install a full image (initialization / checkpoint restore path)."""
-        value = np.array(value, copy=True)
+        value = np.array(value, copy=True, order="C")
         if count_writes:
             self.stats.checkpoint_writes += obj_num_blocks(value, self.block_bytes)
         self._store[name] = value
         self._persist_to_backing(name)
 
     # ------------------------------------------------------------ block writes
-    def writeback_blocks(
-        self, name: str, new_value: np.ndarray, block_mask: np.ndarray
-    ) -> None:
-        """Natural cache eviction: masked blocks of ``new_value`` reach NVM."""
-        cur = self._store[name]
-        n = int(np.count_nonzero(block_mask))
-        if n == 0:
-            return
-        self.stats.eviction_writes += n
-        self._store[name] = mix_blocks(cur, new_value, block_mask, self.block_bytes)
-
     def flush(
         self,
         name: str,
@@ -139,8 +136,8 @@ class NVMArena:
         self.stats.flushed_clean_blocks += total - written
         self.stats.flush_ops += 1
         if written:
-            with span("arena.mix", object=name):
-                self._store[name] = mix_blocks(cur, live_value, mask, self.block_bytes)
+            with span("arena.mix", object=name, blocks=written):
+                mix_blocks_into(cur, live_value, mask, self.block_bytes)
             self._persist_to_backing(name)
         return written
 
@@ -150,7 +147,7 @@ class NVMArena:
         a diff or mask would find every block dirty. Returns the blocks
         written."""
         nb = obj_num_blocks(live_value, self.block_bytes)
-        self._store[name] = np.array(live_value, copy=True)
+        self._store[name] = np.array(live_value, copy=True, order="C")
         self.stats.flush_writes += nb
         self.stats.flush_ops += 1
         self._persist_to_backing(name)
@@ -160,7 +157,7 @@ class NVMArena:
         """Traditional C/R data copy: every block of the object is written."""
         value = np.asarray(value)
         self.stats.checkpoint_writes += obj_num_blocks(value, self.block_bytes)
-        self._store[f"__chk__/{name}"] = np.array(value, copy=True)
+        self._store[f"__chk__/{name}"] = np.array(value, copy=True, order="C")
 
     # -------------------------------------------------------------- durability
     # Backing files follow the shared durable-replace protocol
@@ -222,5 +219,5 @@ class NVMArena:
                     arr = arr.view(want)
                 else:
                     arr = arr.astype(want)
-            arena._store[name] = arr
+            arena._store[name] = np.asarray(arr, order="C")  # a no-op for the C files flush writes
         return arena

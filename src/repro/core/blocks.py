@@ -37,6 +37,44 @@ def _as_byte_view(arr: np.ndarray) -> np.ndarray:
     return a.view(np.uint8).reshape(-1)
 
 
+def mix_blocks_into(
+    dst: np.ndarray,
+    src: np.ndarray,
+    new_block_mask: np.ndarray,
+    block_bytes: int = DEFAULT_BLOCK_BYTES,
+) -> None:
+    """In-place blockwise merge: copy the blocks ``new_block_mask`` marks from
+    ``src`` into ``dst``, which must be C-contiguous and writable.
+
+    Whole blocks move as rows of ``(n_full_blocks, block_bytes)``; a dirty
+    partial final block is copied on its own; a mask with every block set is
+    one copy of the whole image.
+    """
+    src = np.asarray(src)
+    if dst.shape != src.shape or dst.dtype != src.dtype:
+        raise ValueError(f"mix_blocks shape/dtype mismatch: {dst.shape}/{dst.dtype} vs {src.shape}/{src.dtype}")
+    nb = obj_num_blocks(dst, block_bytes)
+    mask = np.asarray(new_block_mask, dtype=bool)
+    if mask.shape != (nb,):
+        raise ValueError(f"mask must have {nb} blocks, got {mask.shape}")
+    if not (dst.flags.c_contiguous and dst.flags.writeable):
+        raise ValueError("mix_blocks_into needs a C-contiguous, writable destination")
+    idx = np.flatnonzero(mask)
+    if idx.size == 0:
+        return
+    d = dst.reshape(-1).view(np.uint8)
+    s = _as_byte_view(src)
+    if idx.size == nb:
+        np.copyto(d, s)
+        return
+    full = d.size // block_bytes
+    if idx[-1] == full:  # the partial final block
+        d[full * block_bytes:] = s[full * block_bytes:]
+        idx = idx[:-1]
+    rows = full * block_bytes
+    d[:rows].reshape(full, block_bytes)[idx] = s[:rows].reshape(full, block_bytes)[idx]
+
+
 def mix_blocks(
     old: np.ndarray,
     new: np.ndarray,
@@ -46,23 +84,12 @@ def mix_blocks(
     """Blockwise select: where ``new_block_mask[b]`` take ``new``, else ``old``.
 
     This is the post-crash NVM image constructor: persisted blocks carry the
-    new value, lost (dirty-in-cache) blocks retain the stale one.
+    new value, lost (dirty-in-cache) blocks retain the stale one. ``old`` is
+    not modified; see :func:`mix_blocks_into` for the in-place merge.
     """
-    old = np.asarray(old)
-    new = np.asarray(new)
-    if old.shape != new.shape or old.dtype != new.dtype:
-        raise ValueError(f"mix_blocks shape/dtype mismatch: {old.shape}/{old.dtype} vs {new.shape}/{new.dtype}")
-    nb = obj_num_blocks(old, block_bytes)
-    mask = np.asarray(new_block_mask, dtype=bool)
-    if mask.shape != (nb,):
-        raise ValueError(f"mask must have {nb} blocks, got {mask.shape}")
-    if nb == 0:
-        return old.copy()
-    ob = _as_byte_view(old).copy()
-    nbv = _as_byte_view(new)
-    byte_mask = np.repeat(mask, block_bytes)[: ob.size]
-    ob[byte_mask] = nbv[byte_mask]
-    return ob.view(old.dtype).reshape(old.shape)
+    out = np.array(old, order="C", copy=True)
+    mix_blocks_into(out, new, new_block_mask, block_bytes)
+    return out
 
 
 def inconsistent_rate(

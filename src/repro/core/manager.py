@@ -179,7 +179,10 @@ class EasyCrashManager:
                 flat = flatten_state(state)
                 sel = self._selected(flat)
                 sel["__step__"] = np.asarray(step, dtype=np.int64)
-                payload = {k: np.array(v, copy=True) for k, v in sel.items()}
+                # row-major, as the arena's blocks are: an array fetched from a
+                # device may come in another order, which every later byte view
+                # (mask, merge, file) would otherwise copy again to reorder
+                payload = {k: np.array(v, copy=True, order="C") for k, v in sel.items()}
                 if tracing():
                     stage.add(nbytes=sum(v.nbytes for v in payload.values()))
             if self.policy.async_flush:

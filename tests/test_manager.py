@@ -1,4 +1,5 @@
 """Production runtime: arena durability, flush/restore, checkpoint fallback."""
+import json
 import os
 
 import numpy as np
@@ -272,3 +273,136 @@ def test_a_state_of_attention_alone_flushes_as_before(tmp_path, monkeypatch):
         runs.append((masked, mgr.stats.blocks_written, mgr.stats.bytes_written,
                      sorted(os.listdir(tmp_path / str(i)))))
     assert runs[0] == runs[1]
+
+
+# ------------------------------------------- masked flushes merge in place
+def _kv(step, positions=12):
+    """A bf16 K object (layers, rows, positions, heads, dims) holding
+    ``step`` decoded positions; one position is 64 bytes, one block."""
+    import ml_dtypes
+
+    kv = np.zeros((2, 3, positions, 4, 8), ml_dtypes.bfloat16)
+    kv[:, :, :step] = np.arange(1, step + 1)[None, None, :, None, None]
+    return kv
+
+
+def _in_order(a, layout):
+    """``a``'s values in another memory order, as a device may hand them back."""
+    if layout == "C":
+        return a
+    if layout == "F":
+        return np.asfortranarray(a)
+    return np.ascontiguousarray(a.transpose(2, 0, 1, 3, 4)).transpose(1, 2, 0, 3, 4)
+
+
+def _file(tmp_path, name, like):
+    return np.load(tmp_path / f"{name}.npy").view(like.dtype)
+
+
+def _delta_flush(arena, step, layout="C"):
+    from repro.core.delta_persist import persist_mask_for
+
+    live = _in_order(_kv(step), layout)
+    mask = persist_mask_for("delta", arena.peek("k"), live, arena.block_bytes)
+    assert mask is not None and 0 < mask.sum() < mask.size
+    assert arena.flush("k", live, dirty_resident_mask=mask) == int(mask.sum())
+    return live
+
+
+@pytest.mark.parametrize("layout", ["C", "permuted"])
+@pytest.mark.parametrize("reattached", [False, True], ids=["open", "reattached"])
+def test_a_masked_flush_merges_into_the_arenas_own_image(tmp_path, reattached, layout):
+    arena = NVMArena(backing_dir=str(tmp_path))
+    arena.flush("k", _in_order(_kv(1), layout))  # first flush: written whole, row-major
+    arena.save_manifest()
+    if reattached:  # the image is now the one np.load gave
+        arena = NVMArena.reattach(str(tmp_path))
+    image = arena.peek("k")
+    assert image.flags.c_contiguous
+    for step in (2, 5):
+        live = _delta_flush(arena, step, layout)
+        assert arena.peek("k") is image  # merged in place, no fresh copy
+        assert image.tobytes() == live.tobytes()
+        assert _file(tmp_path, "k", live).tobytes() == live.tobytes()
+
+
+def test_a_merged_image_shares_no_memory_with_the_flushed_value(tmp_path):
+    arena = NVMArena(backing_dir=str(tmp_path))
+    arena.flush("k", _kv(1))
+    live = _delta_flush(arena, 3)
+    want = live.tobytes()
+    live[...] = 7  # the caller's array, reused after the flush
+    arena.get("k")[...] = 9  # and the copy a load returns
+    assert arena.get("k").tobytes() == want
+    assert _file(tmp_path, "k", live).tobytes() == want
+
+
+@pytest.mark.parametrize("entry", ["reattach", "install"])
+def test_an_f_ordered_image_enters_the_arena_row_major(tmp_path, entry):
+    """An image in Fortran order, from an older arena's file or handed to
+    ``install``, is held row-major, so a masked flush merges into it in place."""
+    first = np.asfortranarray(_kv(1))
+    if entry == "reattach":
+        np.save(tmp_path / "k.npy", first)
+        (tmp_path / "manifest.json").write_text(
+            json.dumps({"block_bytes": 64, "objects": {"k": "bfloat16"}}))
+        arena = NVMArena.reattach(str(tmp_path))
+    else:
+        arena = NVMArena(backing_dir=str(tmp_path))
+        arena.install("k", first)
+    image = arena.peek("k")
+    assert image.flags.c_contiguous and image.flags.writeable
+    assert image.tobytes() == _kv(1).tobytes()
+    live = _delta_flush(arena, 4)
+    assert arena.peek("k") is image
+    assert image.tobytes() == live.tobytes()
+    assert _file(tmp_path, "k", live).tobytes() == live.tobytes()
+
+
+@pytest.mark.parametrize("mode", ["full", "auto"])
+def test_every_mode_leaves_the_files_of_delta_flushes(tmp_path, mode):
+    """"full" marks every block, which merges as one copy of the whole
+    image, and "auto" merges the arena's own diff: the files equal those of
+    delta flushes, and the live values."""
+    def run(m):
+        d = tmp_path / m
+        mgr = EasyCrashManager(NVMArena(backing_dir=str(d)),
+                               FlushPolicy(leaves=("k",), every_steps=1, async_flush=False,
+                                           persist_mode=m))
+        for step in (1, 2, 6):
+            mgr.maybe_flush(step, {"k": _kv(step)})
+        mgr.close()
+        return {f: (d / f).read_bytes() for f in sorted(os.listdir(d))}
+
+    assert run(mode) == run("delta")
+    assert _file(tmp_path / mode, "k", _kv(6)).tobytes() == _kv(6).tobytes()
+
+
+@pytest.mark.parametrize("layout", ["F", "permuted"])
+def test_a_value_in_another_memory_order_is_staged_row_major(tmp_path, layout, monkeypatch):
+    """The arena's blocks are row-major bytes: a live value in another order
+    is staged row-major once, so its image is merged in place and its files
+    are those of the same values in row-major order."""
+    staged = []
+    real_flush = NVMArena.flush
+    monkeypatch.setattr(NVMArena, "flush", lambda self, name, live, *a, **k: staged.append(
+        live.flags.c_contiguous) or real_flush(self, name, live, *a, **k))
+
+    def run(d, order):
+        mgr = EasyCrashManager(NVMArena(backing_dir=str(d)),
+                               FlushPolicy(leaves=("k",), every_steps=1, async_flush=False,
+                                           persist_mode="delta"))
+        images = []
+        for step in (1, 2, 5):
+            live = order(_kv(step))
+            mgr.maybe_flush(step, {"k": live})
+            images.append(mgr.arena.peek("k"))
+        mgr.close()
+        assert all(img is images[0] for img in images)
+        assert images[0].flags.c_contiguous
+        return {f: (d / f).read_bytes() for f in sorted(os.listdir(d))}
+
+    other = run(tmp_path / layout, lambda a: _in_order(a, layout))
+    assert not _in_order(_kv(1), layout).flags.c_contiguous
+    assert other == run(tmp_path / "C", lambda a: a)
+    assert len(staged) == 12 and all(staged)  # k and the step, 3 flushes, 2 runs

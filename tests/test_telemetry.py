@@ -102,6 +102,15 @@ def test_each_span_occurs_once_per_piece_of_work(sessions, name, count):
     assert len(_named(sessions, name)) == count
 
 
+def test_each_merge_counts_the_blocks_its_mask_marked(sessions):
+    masks = [(m[4]["object"], m[4]["dirty_blocks"]) for m in _named(sessions, "flush.mask")
+             if m[4]["object"] != "tokens"]
+    # the first flush writes every object whole; the growing token buffer
+    # is rewritten whole at every flush
+    assert [(m[4]["object"], m[4]["blocks"]) for m in _named(sessions, "arena.mix")] == (
+        masks[len(OBJECTS) - 1:])
+
+
 def test_spans_carry_their_stats(sessions):
     (s,) = _named(sessions, "serve.session")
     assert s[4]["prompts"] == PROMPTS and s[4]["decode_steps"] == STEPS
