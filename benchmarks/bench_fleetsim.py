@@ -71,8 +71,7 @@ def decode_profile(fast: bool = True):
     import time
 
     arena = NVMArena(block_bytes=64)
-    mgr = EasyCrashManager(arena, FlushPolicy(
-        leaves=tuple(app.candidates), async_flush=False, persist_mode="delta"))
+    mgr = EasyCrashManager(arena, FlushPolicy(leaves=tuple(app.candidates)))
     s = app.init(0)
     n_steps, dt = 6, 0.0
     for step in range(1, n_steps + 1):
@@ -80,7 +79,6 @@ def decode_profile(fast: bool = True):
         s = app.run_iteration(s)
         dt += time.perf_counter() - t0
         mgr.maybe_flush(step, {k: np.asarray(v) for k, v in s.items()})
-    mgr.close()
     # the arena keeps no files, so the traffic is its dirty blocks
     t_s = persist_overhead_fraction(
         mgr.stats.blocks_written * arena.block_bytes / n_steps, max(dt / n_steps, 1e-6)

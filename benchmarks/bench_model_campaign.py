@@ -44,8 +44,7 @@ def _persist_traffic(app, n_steps: int = 6):
         arena = NVMArena(block_bytes=64)
         mgr = EasyCrashManager(
             arena,
-            FlushPolicy(leaves=tuple(app.candidates), async_flush=False,
-                        persist_mode=mode),
+            FlushPolicy(leaves=tuple(app.candidates), persist_mode=mode),
         )
         s = app.init(0)
         dt = 0.0
@@ -54,7 +53,6 @@ def _persist_traffic(app, n_steps: int = 6):
                 s = app.run_iteration(s)
             dt += t.dt
             mgr.maybe_flush(step, {k: np.asarray(v) for k, v in s.items()})
-        mgr.close()
         # the arena keeps no files, so the traffic is its dirty blocks
         out[mode] = mgr.stats.blocks_written * arena.block_bytes / n_steps
         out["step_time"] = dt / n_steps

@@ -17,7 +17,7 @@ from .adaptive import (
     weighted_outcome_stats,
     wilson_interval,
 )
-from .arena import NVMArena, WriteStats
+from .arena import NVMArena
 from .blocks import (
     DEFAULT_BLOCK_BYTES,
     block_diff_mask,
@@ -76,7 +76,7 @@ from .artifacts import (
     save_static_plan,
     save_workflow,
 )
-from .delta_persist import delta_block_mask, persist_mask_for
+from .delta_persist import delta_block_mask
 from .efficiency import (
     SystemConfig,
     efficiency_with,
@@ -121,7 +121,7 @@ from .workflow import (
 )
 
 __all__ = [
-    "NVMArena", "WriteStats", "DEFAULT_BLOCK_BYTES", "block_diff_mask",
+    "NVMArena", "DEFAULT_BLOCK_BYTES", "block_diff_mask",
     "inconsistent_rate", "mix_blocks", "num_blocks", "CacheConfig", "Flush",
     "RegionEvents", "Sweep", "TornBlock", "resolve_window_images",
     "simulate_window", "simulate_window_vec", "ENGINES",
@@ -137,7 +137,7 @@ __all__ = [
     "load_plan", "load_profile", "load_static_plan", "load_workflow",
     "profile_from_workflow", "replay_plan", "save_plan", "save_profile",
     "save_static_plan", "save_workflow",
-    "SystemConfig", "delta_block_mask", "persist_mask_for",
+    "SystemConfig", "delta_block_mask",
     "efficiency_with", "efficiency_without", "expected_overhead",
     "persist_overhead_fraction", "scale_mtbf", "tau_threshold",
     "POLICIES", "FailureTrace", "PoissonTrace", "RecomputeProfile",

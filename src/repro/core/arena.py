@@ -1,57 +1,25 @@
-"""NVM arena: the persistent image of application data objects.
+"""NVM arena: durable named images and their files.
 
 The arena emulates NVM-as-main-memory in *app-direct* mode (paper §2.3):
-a byte-addressable persistent region that survives crashes.  Two concerns
-live here:
-
-* value storage — one numpy array per named data object (the "NVM image"),
-  optionally backed by memory-mapped files so a killed process can reattach
-  (the memory-mapped-file offset mechanism the paper describes);
-* write accounting — every block written back (by an explicit flush or by
-  a checkpoint copy) is counted, reproducing the paper's Fig 9
-  endurance comparison.  Flushing a clean or non-resident block costs no
-  NVM write, which is the asymmetry EasyCrash exploits.
+a byte-addressable persistent region that survives crashes.  It holds one
+row-major numpy array per named data object (the "NVM image"), optionally
+backed by one file per object plus a manifest, written by the durable
+replace protocol, so a killed process can reattach to the last images it
+persisted.  The arena merges and writes what it is given; which blocks of
+an object a flush writes is the caller's decision
+(:class:`~repro.core.manager.EasyCrashManager`).
 """
 from __future__ import annotations
 
 import json
 import os
-from dataclasses import dataclass, field
-from typing import Dict, Iterable, Mapping, Optional
+from typing import Dict, Iterable, Optional
 
 import numpy as np
 
 from ..telemetry import span
-from .blocks import (
-    DEFAULT_BLOCK_BYTES,
-    block_diff_mask,
-    mix_blocks_into,
-    obj_num_blocks,
-)
+from .blocks import DEFAULT_BLOCK_BYTES, mix_blocks_into, obj_num_blocks
 from .durable import durable_replace
-
-
-@dataclass
-class WriteStats:
-    """NVM write counters, in units of blocks."""
-
-    flush_writes: int = 0        # EasyCrash persistence operations
-    checkpoint_writes: int = 0   # C/R data copies
-    flush_ops: int = 0           # number of persistence operations issued
-    flushed_clean_blocks: int = 0  # blocks flushed that caused no write
-
-    @property
-    def total(self) -> int:
-        return self.flush_writes + self.checkpoint_writes
-
-    def as_dict(self) -> Dict[str, int]:
-        return {
-            "flush_writes": self.flush_writes,
-            "checkpoint_writes": self.checkpoint_writes,
-            "flush_ops": self.flush_ops,
-            "flushed_clean_blocks": self.flushed_clean_blocks,
-            "total": self.total,
-        }
 
 
 class NVMArena:
@@ -69,7 +37,6 @@ class NVMArena:
         self.block_bytes = int(block_bytes)
         self.backing_dir = backing_dir
         self._store: Dict[str, np.ndarray] = {}
-        self.stats = WriteStats()
         #: bytes written to the objects' backing files, headers included
         self.file_bytes = 0
         if backing_dir:
@@ -94,47 +61,31 @@ class NVMArena:
         """
         return self._store.get(name)
 
-    def snapshot(self) -> Dict[str, np.ndarray]:
-        return {k: v.copy() for k, v in self._store.items()}
-
-    def install(self, name: str, value: np.ndarray, count_writes: bool = False) -> None:
+    def install(self, name: str, value: np.ndarray) -> None:
         """Install a full image (initialization / checkpoint restore path)."""
-        value = np.array(value, copy=True, order="C")
-        if count_writes:
-            self.stats.checkpoint_writes += obj_num_blocks(value, self.block_bytes)
-        self._store[name] = value
+        self._store[name] = np.array(value, copy=True, order="C")
         self._persist_to_backing(name)
 
     # ------------------------------------------------------------ block writes
-    def flush(
-        self,
-        name: str,
-        live_value: np.ndarray,
-        dirty_resident_mask: Optional[np.ndarray] = None,
-    ) -> int:
-        """EasyCrash persistence operation (CLWB semantics).
+    def flush(self, name: str, live_value: np.ndarray, mask: np.ndarray) -> int:
+        """EasyCrash persistence operation: merge the blocks ``mask`` marks
+        dirty (one flag per block of the image) from ``live_value`` into the
+        object's image, in place, and persist the image if any block moved.
 
-        Every block of the object is *issued*, but only blocks that are dirty
-        and resident in the cache cause an NVM write.  When no cache model is
-        attached (production runtime), ``dirty_resident_mask=None`` falls back
-        to a value diff against the current NVM image — the delta_snapshot
-        kernel's behaviour, which is a superset of "dirty and resident"
-        (an evicted-then-clean block diffs as unchanged).
-        Returns the number of blocks actually written.
+        The image must exist at the value's byte size; a first flush, or an
+        object that changed size, goes to :meth:`rewrite`.
+        Returns the number of blocks written.
         """
         live_value = np.asarray(live_value)
         cur = self._store.get(name)
         if cur is None or cur.nbytes != live_value.nbytes:
-            # first flush, or the object was reallocated/grown
-            return self.rewrite(name, live_value)
-        if dirty_resident_mask is None:
-            dirty_resident_mask = block_diff_mask(cur, live_value, self.block_bytes)
-        mask = np.asarray(dirty_resident_mask, dtype=bool)
+            raise ValueError(f"{name!r}: no image of {live_value.nbytes} bytes to merge "
+                             "into; a first or resized flush is a rewrite")
+        mask = np.asarray(mask, dtype=bool)
+        nb = obj_num_blocks(cur, self.block_bytes)
+        if mask.shape != (nb,):
+            raise ValueError(f"{name!r}: mask of shape {mask.shape} for {nb} blocks")
         written = int(np.count_nonzero(mask))
-        total = mask.size
-        self.stats.flush_writes += written
-        self.stats.flushed_clean_blocks += total - written
-        self.stats.flush_ops += 1
         if written:
             with span("arena.mix", object=name, blocks=written):
                 mix_blocks_into(cur, live_value, mask, self.block_bytes)
@@ -143,21 +94,12 @@ class NVMArena:
 
     def rewrite(self, name: str, live_value: np.ndarray) -> int:
         """Persistence operation that writes every block of the object: for
-        a first flush, and for an object that each step rewrites whole, where
-        a diff or mask would find every block dirty. Returns the blocks
-        written."""
-        nb = obj_num_blocks(live_value, self.block_bytes)
+        a first flush, a resized object, and an object that each step
+        rewrites whole, where a mask would find every block dirty. Returns
+        the blocks written."""
         self._store[name] = np.array(live_value, copy=True, order="C")
-        self.stats.flush_writes += nb
-        self.stats.flush_ops += 1
         self._persist_to_backing(name)
-        return nb
-
-    def checkpoint_copy(self, name: str, value: np.ndarray) -> None:
-        """Traditional C/R data copy: every block of the object is written."""
-        value = np.asarray(value)
-        self.stats.checkpoint_writes += obj_num_blocks(value, self.block_bytes)
-        self._store[f"__chk__/{name}"] = np.array(value, copy=True, order="C")
+        return obj_num_blocks(live_value, self.block_bytes)
 
     # -------------------------------------------------------------- durability
     # Backing files follow the shared durable-replace protocol

@@ -14,8 +14,6 @@ contract (and therefore the persisted image) is the same either way.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import numpy as np
 
 from ..kernels.delta_snapshot import dirty_block_mask
@@ -41,30 +39,3 @@ def delta_block_mask(
         return np.zeros((0,), dtype=bool)
     mask = np.asarray(dirty_block_mask(bv, av, block_elems=int(block_bytes)))
     return mask.astype(bool)
-
-
-def persist_mask_for(
-    mode: str,
-    cur: Optional[np.ndarray],
-    live: np.ndarray,
-    block_bytes: int = DEFAULT_BLOCK_BYTES,
-) -> Optional[np.ndarray]:
-    """Resolve a :class:`FlushPolicy.persist_mode` to an arena flush mask.
-
-    ``None`` means "let the arena decide" (its own byte diff — the cache-model
-    superset behaviour).  ``cur`` is the current NVM image (``arena.peek``),
-    or ``None`` when the object has never been persisted / was reallocated,
-    in which case the arena full-writes regardless of any mask.
-    """
-    if mode == "auto":
-        return None
-    live = np.asarray(live)
-    if cur is None or cur.nbytes != live.nbytes:
-        return None  # first flush / reallocation: arena full-writes
-    if mode == "full":
-        from .blocks import obj_num_blocks
-
-        return np.ones(obj_num_blocks(live, block_bytes), dtype=bool)
-    if mode == "delta":
-        return delta_block_mask(cur, live, block_bytes)
-    raise ValueError(f"unknown persist_mode {mode!r}; use 'auto', 'full' or 'delta'")

@@ -3,14 +3,13 @@
 This is the framework-facing layer: given a train-state pytree and a
 :class:`PersistPlan`-style policy, the manager
 
-* flushes the plan's state leaves to a host-local :class:`NVMArena`
-  (asynchronously, on a writer thread — a straggling host never blocks the
-  step, and a skipped flush only increases staleness, which EasyCrash
-  tolerates by construction);
-* performs delta flushes: only blocks that changed since the last flush
-  move, as flagged by the ``delta_snapshot`` Pallas kernel; objects that
-  every step rewrites whole (a model's recurrent state, named by the
-  state's layout, not by a user) are written whole with no mask;
+* flushes the plan's state leaves to a host-local :class:`NVMArena` on the
+  policy's cadence, synchronously, in the caller's thread;
+* decides, per object, how a flush writes it: masked (only blocks that
+  changed since the last flush move, as flagged by the ``delta_snapshot``
+  Pallas kernel) or whole (every block: in ``"full"`` mode, and for objects
+  that every step rewrites whole, such as a model's recurrent state, named
+  by the state's layout, not by a user);
 * takes full coordinated checkpoints at the Young interval stretched by the
   measured recomputability (MTBF' = MTBF / (1 - R));
 * on restart, tries the EasyCrash path (arena image + acceptance
@@ -21,10 +20,7 @@ has zero cross-host traffic, so it scales to arbitrarily many nodes.
 """
 from __future__ import annotations
 
-import queue
-import threading
-import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -72,39 +68,33 @@ def unflatten_state(flat: Mapping[str, np.ndarray]) -> Dict[str, Any]:
 class FlushPolicy:
     """Production analogue of :class:`PersistPlan`.
 
-    ``leaves``: state leaves (flat names, prefix match allowed) to persist.
+    ``leaves``: state leaves to persist (flat names; a name also selects
+    the leaves under it, ``"cache"`` selects ``"cache/t"``).
     ``every_steps``: flush cadence in optimizer steps (the 'frequency x').
-    ``async_flush``: persist on a background thread (drops to sync in tests).
-    ``max_pending``: back-pressure bound; beyond it flushes are *skipped*
-    (bounded staleness instead of a stalled step — straggler mitigation).
     ``persist_mode``: which blocks a flush moves to NVM —
-    ``"auto"`` (arena's own byte diff), ``"delta"`` (incremental: changed
-    blocks only, detected by the ``delta_snapshot`` kernel) or ``"full"``
-    (whole-object rewrite, the C/R-style baseline).  All three produce
-    byte-identical NVM images; they differ in the blocks they mark dirty,
-    which ``ManagerStats.blocks_written`` counts.  ``bytes_written`` counts
-    what the arena's backing files received: an object with any dirty block
-    is rewritten whole.
+    ``"delta"`` (incremental: changed blocks only, detected by the
+    ``delta_snapshot`` kernel) or ``"full"`` (whole-object rewrite, the
+    C/R-style baseline).  Both produce byte-identical NVM images; they differ
+    in the blocks they write, which ``ManagerStats.blocks_written`` counts.
+    ``bytes_written`` counts what the arena's backing files received: an
+    object with any dirty block is rewritten whole.
     """
 
     leaves: Tuple[str, ...]
     every_steps: int = 1
-    async_flush: bool = True
-    max_pending: int = 2
-    persist_mode: str = "auto"
+    persist_mode: str = "delta"
 
     def __post_init__(self):
-        if self.persist_mode not in ("auto", "delta", "full"):
+        if self.persist_mode not in ("delta", "full"):
             raise ValueError(
-                f"unknown persist_mode {self.persist_mode!r}; use 'auto', 'delta' or 'full'"
+                f"unknown persist_mode {self.persist_mode!r}; use 'delta' or 'full'"
             )
 
 
 @dataclass
 class ManagerStats:
     flushes_issued: int = 0
-    flushes_skipped: int = 0
-    #: dirty blocks the flushes wrote into the arena's images
+    #: blocks the flushes wrote into the arena's images
     blocks_written: int = 0
     #: bytes the flushes wrote to the arena's backing files (0 without files)
     bytes_written: int = 0
@@ -136,12 +126,6 @@ class EasyCrashManager:
         self.checkpoint_save = checkpoint_save
         self.checkpoint_restore = checkpoint_restore
         self.stats = ManagerStats()
-        self._q: "queue.Queue[Optional[Tuple[int, Dict[str, np.ndarray]]]]" = queue.Queue()
-        self._worker: Optional[threading.Thread] = None
-        self._last_error: Optional[BaseException] = None
-        if policy.async_flush:
-            self._worker = threading.Thread(target=self._drain, daemon=True)
-            self._worker.start()
         # checkpoint cadence in *steps*, from Young's formula on the stretched
         # MTBF (paper §7); None disables periodic checkpoints.
         self.checkpoint_every: Optional[int] = None
@@ -152,8 +136,6 @@ class EasyCrashManager:
     # ------------------------------------------------------------------ flush
     @staticmethod
     def _match(name: str, leaf: str) -> bool:
-        if leaf.endswith("*"):
-            return name.startswith(leaf[:-1])
         return name == leaf or name.startswith(leaf + "/")
 
     def _selected(self, flat: Mapping[str, np.ndarray]) -> Dict[str, np.ndarray]:
@@ -169,9 +151,9 @@ class EasyCrashManager:
         return step % self.policy.every_steps == 0
 
     def maybe_flush(self, step: int, state: Mapping[str, Any]) -> bool:
-        """Issue an EasyCrash persistence op if the cadence says so.
+        """Flush the policy's leaves of ``state`` if the cadence says so.
 
-        Returns True if a flush was issued (or enqueued)."""
+        Returns True if a flush ran."""
         if not self.due(step):
             return False
         with span("flush", step=step, mode=self.policy.persist_mode):
@@ -185,72 +167,39 @@ class EasyCrashManager:
                 payload = {k: np.array(v, copy=True, order="C") for k, v in sel.items()}
                 if tracing():
                     stage.add(nbytes=sum(v.nbytes for v in payload.values()))
-            if self.policy.async_flush:
-                if self._q.qsize() >= self.policy.max_pending:
-                    self.stats.flushes_skipped += 1   # straggler mitigation: skip
-                    return False
-                self._q.put((step, payload))
-            else:
-                self._flush_now(step, payload)
+            self._flush_now(step, payload)
         self.stats.flushes_issued += 1
         return True
 
     def _flush_now(self, step: int, payload: Mapping[str, np.ndarray]) -> None:
-        from .delta_persist import persist_mask_for
+        """Write each object of ``payload`` into the arena: the one place that
+        decides whether an object is written whole or masked."""
+        from . import delta_persist  # looked up per call, so it can be wrapped
 
         block = self.arena.block_bytes
         file_bytes = self.arena.file_bytes
+        full = self.policy.persist_mode == "full"
         for name, arr in payload.items():
-            if any(self._match(name, leaf) for leaf in self.rewritten):
-                with span("flush.whole", object=name, nbytes=arr.nbytes,
-                          blocks=obj_num_blocks(arr, block)):
+            blocks = obj_num_blocks(arr, block)
+            if full or any(self._match(name, leaf) for leaf in self.rewritten):
+                with span("flush.whole", object=name, nbytes=arr.nbytes, blocks=blocks):
                     self.stats.blocks_written += self.arena.rewrite(name, arr)
                 continue
             cur = self.arena.peek(name)
+            mask = None
             with span("flush.mask", object=name, nbytes=arr.nbytes,
                       block_bytes=block) as s:
-                mask = persist_mask_for(self.policy.persist_mode, cur, arr, block)
+                if cur is not None and cur.nbytes == arr.nbytes:
+                    mask = delta_persist.delta_block_mask(cur, arr, block)
                 if tracing():
-                    blocks = obj_num_blocks(arr, block)
-                    if mask is not None:
-                        s.add(blocks=blocks, dirty_blocks=int(np.count_nonzero(mask)))
-                    elif cur is None or cur.nbytes != arr.nbytes:
-                        s.add(blocks=blocks, dirty_blocks=blocks)  # first flush: all
-                    else:
-                        s.add(blocks=blocks)  # "auto": the arena diffs
-            written = self.arena.flush(name, arr, dirty_resident_mask=mask)
-            self.stats.blocks_written += written
+                    dirty = blocks if mask is None else int(np.count_nonzero(mask))
+                    s.add(blocks=blocks, dirty_blocks=dirty)
+            if mask is None:  # a first flush, or an object that changed size
+                self.stats.blocks_written += self.arena.rewrite(name, arr)
+            else:
+                self.stats.blocks_written += self.arena.flush(name, arr, mask)
         self.arena.save_manifest()
         self.stats.bytes_written += self.arena.file_bytes - file_bytes
-
-    def _drain(self) -> None:
-        while True:
-            item = self._q.get()
-            if item is None:
-                return
-            try:
-                self._flush_now(*item)
-            except BaseException as e:  # surfaced on barrier()
-                self._last_error = e
-
-    def barrier(self) -> None:
-        """Wait for all pending flushes (checkpoint/shutdown boundary)."""
-        if self.policy.async_flush:
-            while not self._q.empty():
-                time.sleep(0.001)
-            # one more roundtrip so an in-flight item finishes
-            self._q.put((int(-1), {}))
-            while not self._q.empty():
-                time.sleep(0.001)
-        if self._last_error is not None:
-            raise self._last_error
-
-    def close(self) -> None:
-        if self._worker is not None:
-            self.barrier()
-            self._q.put(None)
-            self._worker.join(timeout=5)
-            self._worker = None
 
     # ------------------------------------------------------------- checkpoint
     def maybe_checkpoint(self, step: int, state: Mapping[str, Any]) -> bool:
@@ -261,7 +210,6 @@ class EasyCrashManager:
             or step % self.checkpoint_every != 0
         ):
             return False
-        self.barrier()
         self.checkpoint_save(step, state)
         self.stats.checkpoints_taken += 1
         return True

@@ -130,8 +130,8 @@ def spearman(x: Sequence[float], y: Sequence[float]) -> Tuple[float, float]:
 # Result/value dataclasses in core/ are frozen: several (CacheConfig,
 # PersistPlan) appear as shared default parameter values, and the rest are
 # outputs whose silent in-place mutation would desynchronise stores,
-# fingerprints and artifacts.  Mutable-by-design counters (WriteStats,
-# ManagerStats) stay unfrozen.
+# fingerprints and artifacts.  Mutable-by-design counters (ManagerStats)
+# stay unfrozen.
 @dataclass(frozen=True)
 class ObjectScore:
     name: str

@@ -27,7 +27,7 @@ import numpy as np
 from ..compile_cache import enable_compile_cache
 from ..configs import get_arch
 from ..core.arena import NVMArena
-from ..core.manager import EasyCrashManager, FlushPolicy, flatten_state
+from ..core.manager import EasyCrashManager, FlushPolicy, unflatten_state
 from ..models import init_cache, init_params, rewritten_leaves, scaled_down
 from ..telemetry import span, tracing
 from .steps import make_decode_fn, make_prefill_step
@@ -63,7 +63,7 @@ def _serve(args, session: span) -> Dict[str, float]:
             resumed = False
         session.add(resumed=resumed)
         policy = FlushPolicy(leaves=("cache", "tokens"), every_steps=args.flush_every,
-                             async_flush=False, persist_mode=args.persist_mode)
+                             persist_mode=args.persist_mode)
         rewritten = tuple(f"cache/{leaf}" for leaf in rewritten_leaves(cfg))
         mgr = EasyCrashManager(arena, policy, rewritten=rewritten)
 
@@ -76,8 +76,6 @@ def _serve(args, session: span) -> Dict[str, float]:
         start = int(arena.get("__step__"))
         print(f"[restore] resuming decode at step {start} from arena")
         flat = {n: arena.get(n) for n in arena.names() if not n.startswith("__")}
-        from ..core.manager import unflatten_state
-
         state = unflatten_state(flat)
         cache = jax.tree.map(jnp.asarray, state["cache"])
         all_tokens = [jnp.asarray(state["tokens"])]
@@ -117,7 +115,6 @@ def _serve(args, session: span) -> Dict[str, float]:
         "output_shape": list(out.shape),
     }
     print("[done]", stats)
-    mgr.close()
     stats["tokens"] = out
     return stats
 
@@ -147,70 +144,6 @@ def _cache_bytes(cache, rewritten) -> Dict[str, int]:
     return out
 
 
-def fleet_report(stats: Dict[str, float], args) -> Dict[str, dict]:
-    """Project this server's *measured* serving process onto a replica fleet.
-
-    The single-process run measures the two quantities the fleet simulator
-    needs from the real system: the per-step decode time (service rate) and
-    the flush traffic to the arena's files (``bytes_written`` -> ``t_s`` via
-    :func:`~repro.core.efficiency.persist_overhead_fraction`).  Everything
-    else — arrivals, failures, recovery policy — is simulated, so the same
-    binary answers "what would this server's goodput/p99 look like across N
-    replicas under paper-like failure rates?".
-    """
-    from ..core import (
-        POLICIES,
-        ArrivalProcess,
-        FleetConfig,
-        PoissonTrace,
-        RecomputeProfile,
-        ServiceModel,
-        SystemConfig,
-        fleet_frontier,
-        persist_overhead_fraction,
-    )
-
-    steps = max(int(stats["decode_steps"]), 1)
-    step_time = args.prompts / max(stats["tokens_per_s"], 1e-9)
-    t_s = persist_overhead_fraction(stats["bytes_written"] / steps, step_time)
-    # decode sessions are S1-dominant (the KV cache is the session and it is
-    # what we persist); the tail mirrors the decode campaign's shape
-    profile = RecomputeProfile.from_fractions(
-        "serve", {"S1": 0.9, "S2": 0.06, "S3": 0.02, "S4": 0.02},
-        extra_iters_hist=((2, 3), (8, 1)),
-    )
-    service_s = args.decode_steps * step_time
-    rate = args.fleet_rate
-    if rate <= 0:  # auto: offer ~80% of fleet capacity at the measured speed
-        rate = 0.8 * args.fleet_replicas / max(service_s, 1e-3)
-    cfg = FleetConfig(
-        n_replicas=args.fleet_replicas,
-        arrival=ArrivalProcess(rate=rate, amplitude=0.3),
-        service=ServiceModel(mean_s=max(service_s, 1e-3), sigma=0.6,
-                             prefill_s=max(args.prompt_len * step_time, 1e-3)),
-        trace=PoissonTrace(mtbf=args.fleet_mtbf),
-        system=SystemConfig(mtbf=args.fleet_mtbf, t_chk=30.0,
-                            nvm_restore_time=2.0),
-        slo_latency=4.0 * max(service_s, 1e-3),
-        queue_cap=48,
-        horizon=args.fleet_horizon,
-        t_s=t_s,
-        t_iter=step_time,
-        seed=args.seed,
-    )
-    print(f"[fleet] measured t_s={t_s:.4f} step={step_time*1e3:.2f}ms "
-          f"service={service_s:.2f}s; {cfg.n_replicas} replicas, "
-          f"mtbf={cfg.trace.mtbf:.0f}s, horizon={cfg.horizon:.0f}s")
-    doc = fleet_frontier(cfg, profile)
-    for policy in POLICIES:
-        p = doc["policies"][policy]
-        print(f"[fleet] {policy:10s} goodput={p['goodput']:.3f}rps "
-              f"loss={p['dropped']/max(p['arrived'],1):.3f} "
-              f"slo={p['slo_violation_frac']:.3f} "
-              f"p99={p['latency_p99']:.2f}s fails={p['n_failures']}")
-    return doc["policies"]
-
-
 def _splice_cache(cfg, full_cache, prefill_cache, prompt_len: int):
     """Install prefill K/V into the right-sized decode cache."""
     def splice(dst, src):
@@ -236,36 +169,21 @@ def main(argv=None) -> Dict[str, object]:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--decode-steps", type=int, default=64)
     ap.add_argument("--flush-every", type=int, default=8)
-    ap.add_argument("--persist-mode", default="delta",
-                    choices=("auto", "delta", "full"),
-                    help="flush granularity: arena byte diff / delta_snapshot "
-                         "kernel (changed blocks only) / whole-object rewrite")
+    ap.add_argument("--persist-mode", default="delta", choices=("delta", "full"),
+                    help="flush granularity: delta_snapshot kernel (changed "
+                         "blocks only) / whole-object rewrite")
     ap.add_argument("--workdir",
                     default=os.path.join(tempfile.gettempdir(), "repro_serve"))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--inject-failure-at", type=int, default=0)
-    ap.add_argument("--fleet", action="store_true",
-                    help="after serving, project the measured step time and "
-                         "persist traffic onto a replica fleet under "
-                         "failures (repro.core.fleetsim policy comparison)")
-    ap.add_argument("--fleet-replicas", type=int, default=4)
-    ap.add_argument("--fleet-rate", type=float, default=0.0,
-                    help="fleet offered load, requests/s "
-                         "(<= 0: auto, ~80%% of measured fleet capacity)")
-    ap.add_argument("--fleet-mtbf", type=float, default=900.0,
-                    help="per-replica MTBF, seconds")
-    ap.add_argument("--fleet-horizon", type=float, default=1800.0)
     args = ap.parse_args(argv)
     enable_compile_cache()
     try:
-        stats = run(args)
+        return run(args)
     except SimulatedFailure as e:
         print(f"[failure] {e}; restarting...")
         args.inject_failure_at = 0
-        stats = run(args)
-    if args.fleet:
-        fleet_report(stats, args)
-    return stats
+        return run(args)
 
 
 if __name__ == "__main__":

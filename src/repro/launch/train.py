@@ -5,7 +5,7 @@ wiring together every fault-tolerance layer this framework provides:
 
   * EasyCrash flushes of the *critical* state subset (params + step — the
     selection the crash campaigns find; Adam moments re-warm) to a
-    host-local NVM arena, asynchronously, every ``--flush-every`` steps;
+    host-local NVM arena, in the step loop, every ``--flush-every`` steps;
   * multilevel checkpoints at the Young interval stretched by measured
     recomputability (MTBF' = MTBF / (1 - R));
   * deterministic, seekable data (restart needs only the step counter);
@@ -23,7 +23,7 @@ import argparse
 import os
 import tempfile
 import time
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +33,7 @@ from ..checkpoint import CheckpointConfig, CheckpointManager
 from ..compile_cache import enable_compile_cache
 from ..configs import get_arch
 from ..core.arena import NVMArena
-from ..core.manager import EasyCrashManager, FlushPolicy, flatten_state, unflatten_state
+from ..core.manager import EasyCrashManager, FlushPolicy
 from ..data import DataConfig, SyntheticLMStream
 from ..models import scaled_down
 from .steps import init_train_state, make_train_step
@@ -88,7 +88,6 @@ def run(args) -> Dict[str, float]:
 
     policy = FlushPolicy(
         leaves=("params", "step"), every_steps=args.flush_every,
-        async_flush=not args.sync_flush,
         persist_mode=args.persist_mode,
     )
     mgr = EasyCrashManager(
@@ -146,19 +145,15 @@ def run(args) -> Dict[str, float]:
             mgr.maybe_checkpoint(step, host_state)
             if args.inject_failure_every and step % args.inject_failure_every == 0 \
                     and step < args.steps:
-                mgr.barrier()  # crash strikes after in-flight flushes land
                 raise SimulatedFailure(f"injected failure at step {step}")
     finally:
         stream.close()
 
-    mgr.barrier()
-    mgr.close()
     ckpt.close()
     stats = {
         "final_step": step,
         "final_loss": losses[-1] if losses else float("nan"),
         "flushes": mgr.stats.flushes_issued,
-        "flushes_skipped": mgr.stats.flushes_skipped,
         "blocks_written": mgr.stats.blocks_written,
         "bytes_written": mgr.stats.bytes_written,
         "checkpoints": mgr.stats.checkpoints_taken,
@@ -184,11 +179,9 @@ def main(argv=None) -> None:
     ap.add_argument("--workdir",
                     default=os.path.join(tempfile.gettempdir(), "repro_train"))
     ap.add_argument("--flush-every", type=int, default=1)
-    ap.add_argument("--sync-flush", action="store_true")
-    ap.add_argument("--persist-mode", default="auto",
-                    choices=("auto", "delta", "full"),
-                    help="flush granularity: arena byte diff / delta_snapshot "
-                         "kernel (changed blocks only) / whole-object rewrite")
+    ap.add_argument("--persist-mode", default="delta", choices=("delta", "full"),
+                    help="flush granularity: delta_snapshot kernel (changed "
+                         "blocks only) / whole-object rewrite")
     ap.add_argument("--mtbf", type=float, default=300.0)
     ap.add_argument("--t-chk", type=float, default=5.0)
     ap.add_argument("--recomputability", type=float, default=0.82)

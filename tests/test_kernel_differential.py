@@ -139,7 +139,7 @@ def _persist_series(n, dtype, rng):
 def test_delta_persist_image_matches_full(n, dtype):
     """persist_mode='delta' must leave a byte-identical NVM image to a
     whole-object persist across dtypes and non-multiple-of-block shapes,
-    while writing no more blocks."""
+    while writing only the blocks the CPU reference finds dirty."""
     if dtype == "bfloat16":
         dtype = jnp.bfloat16.dtype
     rng = np.random.default_rng(n)
@@ -147,22 +147,22 @@ def test_delta_persist_image_matches_full(n, dtype):
 
     def run(mode):
         arena = NVMArena(block_bytes=64)
-        mgr = EasyCrashManager(
-            arena, FlushPolicy(leaves=("x",), async_flush=False, persist_mode=mode)
-        )
+        mgr = EasyCrashManager(arena, FlushPolicy(leaves=("x",), persist_mode=mode))
         for step, x in enumerate(series, start=1):
             mgr.maybe_flush(step, {"x": x})
-        mgr.close()
         return arena.get("x"), mgr.stats.blocks_written
 
     img_delta, blocks_delta = run("delta")
     img_full, blocks_full = run("full")
-    img_auto, blocks_auto = run("auto")
-    assert img_delta.tobytes() == img_full.tobytes() == img_auto.tobytes()
+    assert img_delta.tobytes() == img_full.tobytes()
     assert img_delta.dtype == np.dtype(dtype)
-    assert blocks_delta <= blocks_full
-    # delta and the arena's own byte diff agree on what moved
-    assert blocks_delta == blocks_auto
+    # delta: every block at the first flush, then what block_diff_mask finds;
+    # the persisted step is one more block at every flush
+    blocks = -(-series[0].nbytes // 64)
+    dirty = blocks + sum(int(block_diff_mask(a, b, 64).sum())
+                         for a, b in zip(series, series[1:]))
+    assert blocks_delta == dirty + len(series)
+    assert blocks_full == (blocks + 1) * len(series)
     if n > 256:  # multi-block object: the savings must be real
         assert blocks_delta < blocks_full
 

@@ -28,10 +28,9 @@ def test_flatten_roundtrip():
 
 def test_flush_and_restore(tmp_path):
     arena = NVMArena(backing_dir=str(tmp_path))
-    policy = FlushPolicy(leaves=("params",), every_steps=1, async_flush=False)
+    policy = FlushPolicy(leaves=("params",), every_steps=1)
     mgr = EasyCrashManager(arena, policy)
     mgr.maybe_flush(5, _state(5))
-    mgr.close()
 
     # simulate crash: new process reattaches to the arena
     arena2 = NVMArena.reattach(str(tmp_path))
@@ -46,22 +45,20 @@ def test_flush_and_restore(tmp_path):
 
 def test_delta_flush_counts_only_dirty(tmp_path):
     arena = NVMArena(backing_dir=str(tmp_path))
-    policy = FlushPolicy(leaves=("params",), every_steps=1, async_flush=False)
+    policy = FlushPolicy(leaves=("params",), every_steps=1)
     mgr = EasyCrashManager(arena, policy)
     s = _state(1)
     mgr.maybe_flush(1, s)
-    first = arena.stats.flush_writes
+    first = mgr.stats.blocks_written
+    assert first == 4 + 1 + 1  # params/w, params/b and the step, whole
     mgr.maybe_flush(2, s)  # identical values: delta flush writes ~nothing
-    second = arena.stats.flush_writes - first
     # only the __step__ scalar changed
-    assert second <= 1
-    assert arena.stats.flushed_clean_blocks > 0
-    mgr.close()
+    assert mgr.stats.blocks_written - first == 1
 
 
 def test_flush_cadence():
     arena = NVMArena()
-    policy = FlushPolicy(leaves=("params",), every_steps=4, async_flush=False)
+    policy = FlushPolicy(leaves=("params",), every_steps=4)
     mgr = EasyCrashManager(arena, policy)
     issued = [mgr.maybe_flush(s, _state(s)) for s in range(8)]
     assert issued == [True, False, False, False, True, False, False, False]
@@ -69,40 +66,11 @@ def test_flush_cadence():
 
 @pytest.mark.parametrize("every", [1, 3, 8])
 def test_due_is_where_maybe_flush_flushes(every):
-    mgr = EasyCrashManager(NVMArena(), FlushPolicy(leaves=("params",), every_steps=every,
-                                                   async_flush=False))
+    mgr = EasyCrashManager(NVMArena(), FlushPolicy(leaves=("params",), every_steps=every))
     due = [mgr.due(s) for s in range(25)]
     issued = [mgr.maybe_flush(s, _state(s)) for s in range(25)]
     assert due == issued
     assert mgr.stats.flushes_issued == sum(due) == len(range(0, 25, every))
-
-
-def test_async_flush_barrier(tmp_path):
-    arena = NVMArena(backing_dir=str(tmp_path))
-    policy = FlushPolicy(leaves=("params", "opt"), every_steps=1,
-                         async_flush=True, max_pending=16)
-    mgr = EasyCrashManager(arena, policy)
-    for s in range(4):
-        mgr.maybe_flush(s, _state(s))
-    mgr.barrier()
-    assert "params/w" in arena
-    assert int(arena.get("__step__")) == 3
-    mgr.close()
-
-
-def test_async_backpressure_skips():
-    """Straggler mitigation: an overloaded flush queue skips, never blocks."""
-    import threading, queue as q
-
-    arena = NVMArena()
-    policy = FlushPolicy(leaves=("params",), every_steps=1,
-                         async_flush=True, max_pending=1)
-    mgr = EasyCrashManager(arena, policy)
-    # stall the worker by grabbing the queue first
-    for s in range(50):
-        mgr.maybe_flush(s, _state(s))
-    assert mgr.stats.flushes_skipped + mgr.stats.flushes_issued == 50
-    mgr.close()
 
 
 def test_verify_hook_rejects_to_checkpoint(tmp_path):
@@ -118,7 +86,7 @@ def test_verify_hook_rejects_to_checkpoint(tmp_path):
         return saved["step"], saved["state"]
 
     arena = NVMArena(backing_dir=str(tmp_path))
-    policy = FlushPolicy(leaves=("params",), every_steps=1, async_flush=False)
+    policy = FlushPolicy(leaves=("params",), every_steps=1)
     mgr = EasyCrashManager(
         arena, policy, checkpoint_save=save, checkpoint_restore=restore,
         mtbf=3600.0, t_chk=10.0, recomputability=0.8, step_time=60.0,
@@ -135,7 +103,7 @@ def test_verify_hook_rejects_to_checkpoint(tmp_path):
 
 def test_young_checkpoint_interval_stretches_with_recomputability():
     arena = NVMArena()
-    policy = FlushPolicy(leaves=("params",), async_flush=False)
+    policy = FlushPolicy(leaves=("params",))
     low = EasyCrashManager(arena, policy, mtbf=3600.0, t_chk=10.0,
                            recomputability=0.0, step_time=1.0)
     high = EasyCrashManager(arena, policy, mtbf=3600.0, t_chk=10.0,
@@ -168,34 +136,30 @@ def _hybrid_state(step):
 
 def _flush_steps(tmp_path, mode, rewritten, monkeypatch, steps=(1, 2, 3)):
     """Flush the hybrid state at ``steps``; returns the manager and, per
-    flush, the masks asked for: (an image existed, object shape, dirty
-    blocks or None)."""
+    flush, the masks computed: (object shape, dirty blocks)."""
     from repro.core import delta_persist
 
     masked = []
-    real = delta_persist.persist_mask_for
+    real = delta_persist.delta_block_mask
 
-    def record(mode_, cur, live, block_bytes=64):
-        mask = real(mode_, cur, live, block_bytes)
-        masked.append((cur is not None, live.shape,
-                       None if mask is None else int(np.count_nonzero(mask))))
+    def record(cur, live, block_bytes=64):
+        mask = real(cur, live, block_bytes)
+        masked.append((live.shape, int(np.count_nonzero(mask))))
         return mask
 
-    monkeypatch.setattr(delta_persist, "persist_mask_for", record)
+    monkeypatch.setattr(delta_persist, "delta_block_mask", record)
     arena = NVMArena(backing_dir=str(tmp_path))
-    policy = FlushPolicy(leaves=("cache", "tokens"), every_steps=1, async_flush=False,
-                         persist_mode=mode)
+    policy = FlushPolicy(leaves=("cache", "tokens"), every_steps=1, persist_mode=mode)
     mgr = EasyCrashManager(arena, policy, rewritten=rewritten)
     calls = []
     for step in steps:
         before = len(masked)
         mgr.maybe_flush(step, _hybrid_state(step))
         calls.append(masked[before:])
-    mgr.close()
     return mgr, calls
 
 
-@pytest.mark.parametrize("mode", ["auto", "delta", "full"])
+@pytest.mark.parametrize("mode", ["delta", "full"])
 def test_whole_written_objects_open_no_mask(tmp_path, mode, monkeypatch):
     from repro.core.blocks import obj_num_blocks
 
@@ -203,11 +167,13 @@ def test_whole_written_objects_open_no_mask(tmp_path, mode, monkeypatch):
     state = flatten_state(_hybrid_state(3))
     whole = {n for n in state if n.startswith(REWRITTEN[0] + "/")}
     assert whole == {"cache/group0/pos0/ssm", "cache/group0/pos0/conv"}
-    # every other object of the flush, and the step, asks for its mask
+    # in delta mode, every other object of a later flush, and the step, is
+    # masked; but the token buffer, which grows, and is written whole
     for step, call in zip((1, 2, 3), calls):
         flat = flatten_state(_hybrid_state(step))
-        shapes = [str(flat[n].shape) for n in flat if n not in whole] + ["()"]
-        assert sorted(str(shape) for _, shape, _ in call) == sorted(shapes)
+        shapes = [] if mode == "full" or step == 1 else [
+            str(flat[n].shape) for n in flat if n not in whole and n != "tokens"] + ["()"]
+        assert sorted(str(shape) for shape, _ in call) == sorted(shapes)
     # each flush writes every block of the rewritten objects
     per_flush = sum(obj_num_blocks(state[n], 64) for n in whole)
     assert mgr.stats.blocks_written >= 3 * per_flush
@@ -219,11 +185,11 @@ def test_kv_keeps_its_delta_mask_beside_whole_written_state(tmp_path, monkeypatc
     # K and V: written whole at the first flush (no image yet), then masked:
     # a step adds one 16-byte position, one dirty 64-byte block in each of
     # the 2 x 3 (layer, row) slices
-    assert [[(seen, dirty) for seen, shape, dirty in call if shape == kv_shape]
-            for call in calls] == [[(False, None)] * 2, [(True, 6)] * 2, [(True, 6)] * 2]
+    assert [[dirty for shape, dirty in call if shape == kv_shape]
+            for call in calls] == [[], [6] * 2, [6] * 2]
 
 
-@pytest.mark.parametrize("mode", ["auto", "delta", "full"])
+@pytest.mark.parametrize("mode", ["delta", "full"])
 @pytest.mark.parametrize("rewritten", [REWRITTEN, ()], ids=["whole", "masked"])
 def test_arena_images_are_the_flushed_state_byte_for_byte(tmp_path, mode, rewritten,
                                                           monkeypatch):
@@ -258,21 +224,115 @@ def test_a_state_of_attention_alone_flushes_as_before(tmp_path, monkeypatch):
         from repro.core import delta_persist
 
         masked = []
-        real = delta_persist.persist_mask_for
-        monkeypatch.setattr(delta_persist, "persist_mask_for",
-                            lambda m, c, l, b=64, real=real: masked.append(l.shape)
-                            or real(m, c, l, b))
+        real = delta_persist.delta_block_mask
+        monkeypatch.setattr(delta_persist, "delta_block_mask",
+                            lambda c, l, b=64, real=real: masked.append(l.shape)
+                            or real(c, l, b))
         arena = NVMArena(backing_dir=str(tmp_path / str(i)))
         mgr = EasyCrashManager(arena, FlushPolicy(leaves=("cache", "tokens"), every_steps=1,
-                                                  async_flush=False, persist_mode="delta"),
+                                                  persist_mode="delta"),
                                rewritten=rewritten)
         for step in (1, 2, 3):
             mgr.maybe_flush(step, attention_state(step))
-        mgr.close()
-        monkeypatch.setattr(delta_persist, "persist_mask_for", real)
+        monkeypatch.setattr(delta_persist, "delta_block_mask", real)
         runs.append((masked, mgr.stats.blocks_written, mgr.stats.bytes_written,
                      sorted(os.listdir(tmp_path / str(i)))))
-    assert runs[0] == runs[1]
+    assert runs[0][0] and runs[0] == runs[1]
+
+
+# ------------------------------------------------- one decision per object
+class _Span:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def add(self, **more):
+        self.stats.update(more)
+
+
+class _Spans:
+    """Stands in for ``manager.span``: records each span's name and stats."""
+
+    def __init__(self):
+        self.opened = []
+
+    def __call__(self, name, **stats):
+        self.opened.append((name, stats))
+        return _Span(stats)
+
+
+_K1 = np.arange(256, dtype=np.float32)  # 16 blocks
+_K2 = _K1.copy()
+_K2[40] = -1.0  # one dirty block
+
+#: case -> (mode, rewritten, value before (None: no image), value flushed,
+#:          span opened, mask computed, arena method, dirty blocks in the span)
+DECISIONS = {
+    "delta-first": ("delta", (), None, _K1, "flush.mask", False, "rewrite", 16),
+    "delta-later": ("delta", (), _K1, _K2, "flush.mask", True, "flush", 1),
+    "delta-rewritten": ("delta", ("k",), _K1, _K2, "flush.whole", False, "rewrite", None),
+    "full": ("full", (), _K1, _K2, "flush.whole", False, "rewrite", None),
+    "delta-grown": ("delta", (), _K1, np.arange(272, dtype=np.float32), "flush.mask",
+                    False, "rewrite", 17),
+    "delta-unchanged": ("delta", (), _K1, _K1.copy(), "flush.mask", True, "flush", 0),
+}
+
+
+@pytest.mark.parametrize("case", list(DECISIONS))
+def test_the_manager_decides_how_each_object_is_written(tmp_path, case, monkeypatch):
+    from repro.core import delta_persist, manager
+
+    mode, rewritten, before, live, opened, masks, method, dirty = DECISIONS[case]
+    mgr = EasyCrashManager(NVMArena(backing_dir=str(tmp_path)),
+                           FlushPolicy(leaves=("k",), persist_mode=mode), rewritten=rewritten)
+    if before is not None:
+        mgr._flush_now(1, {"k": before})
+    spans, mask_calls, methods = _Spans(), [], []
+    real_mask = delta_persist.delta_block_mask
+    monkeypatch.setattr(manager, "span", spans)
+    monkeypatch.setattr(manager, "tracing", lambda: True)
+    monkeypatch.setattr(delta_persist, "delta_block_mask",
+                        lambda c, l, b=64: mask_calls.append(l.shape) or real_mask(c, l, b))
+    for m in ("flush", "rewrite"):
+        real = getattr(NVMArena, m)
+        monkeypatch.setattr(NVMArena, m, lambda self, *a, m=m, real=real:
+                            methods.append(m) or real(self, *a))
+    blocks, file_bytes = mgr.stats.blocks_written, mgr.stats.bytes_written
+
+    mgr._flush_now(2, {"k": live})
+
+    ((name, stats),) = spans.opened
+    assert name == opened and stats["object"] == "k" and stats["nbytes"] == live.nbytes
+    assert stats["blocks"] == -(-live.nbytes // 64)
+    assert stats.get("dirty_blocks") == dirty
+    assert mask_calls == ([live.shape] if masks else [])
+    assert methods == [method]
+    written = mgr.stats.blocks_written - blocks
+    assert written == (stats["blocks"] if dirty is None else dirty)
+    # an unchanged object reaches no file; any other is written out whole
+    assert (mgr.stats.bytes_written > file_bytes) == (written > 0)
+    assert mgr.arena.get("k").tobytes() == live.tobytes()
+
+
+def test_the_auto_persist_mode_is_refused():
+    with pytest.raises(ValueError, match="'delta' or 'full'"):
+        FlushPolicy(leaves=("k",), persist_mode="auto")
+
+
+@pytest.mark.parametrize("case", ["no image", "resized", "short mask"])
+def test_a_masked_arena_flush_needs_an_image_of_its_size(tmp_path, case):
+    arena = NVMArena(backing_dir=str(tmp_path))
+    live = np.arange(256, dtype=np.float32)
+    if case != "no image":
+        arena.rewrite("k", live[:128] if case == "resized" else live)
+    with pytest.raises(ValueError):
+        arena.flush("k", live, np.zeros(16 if case != "short mask" else 15, bool))
+    assert ("k" in arena) == (case != "no image")
 
 
 # ------------------------------------------- masked flushes merge in place
@@ -300,12 +360,12 @@ def _file(tmp_path, name, like):
 
 
 def _delta_flush(arena, step, layout="C"):
-    from repro.core.delta_persist import persist_mask_for
+    from repro.core.delta_persist import delta_block_mask
 
     live = _in_order(_kv(step), layout)
-    mask = persist_mask_for("delta", arena.peek("k"), live, arena.block_bytes)
-    assert mask is not None and 0 < mask.sum() < mask.size
-    assert arena.flush("k", live, dirty_resident_mask=mask) == int(mask.sum())
+    mask = delta_block_mask(arena.peek("k"), live, arena.block_bytes)
+    assert 0 < mask.sum() < mask.size
+    assert arena.flush("k", live, mask) == int(mask.sum())
     return live
 
 
@@ -313,7 +373,7 @@ def _delta_flush(arena, step, layout="C"):
 @pytest.mark.parametrize("reattached", [False, True], ids=["open", "reattached"])
 def test_a_masked_flush_merges_into_the_arenas_own_image(tmp_path, reattached, layout):
     arena = NVMArena(backing_dir=str(tmp_path))
-    arena.flush("k", _in_order(_kv(1), layout))  # first flush: written whole, row-major
+    arena.rewrite("k", _in_order(_kv(1), layout))  # first flush: written whole, row-major
     arena.save_manifest()
     if reattached:  # the image is now the one np.load gave
         arena = NVMArena.reattach(str(tmp_path))
@@ -328,7 +388,7 @@ def test_a_masked_flush_merges_into_the_arenas_own_image(tmp_path, reattached, l
 
 def test_a_merged_image_shares_no_memory_with_the_flushed_value(tmp_path):
     arena = NVMArena(backing_dir=str(tmp_path))
-    arena.flush("k", _kv(1))
+    arena.rewrite("k", _kv(1))
     live = _delta_flush(arena, 3)
     want = live.tobytes()
     live[...] = 7  # the caller's array, reused after the flush
@@ -359,19 +419,16 @@ def test_an_f_ordered_image_enters_the_arena_row_major(tmp_path, entry):
     assert _file(tmp_path, "k", live).tobytes() == live.tobytes()
 
 
-@pytest.mark.parametrize("mode", ["full", "auto"])
+@pytest.mark.parametrize("mode", ["full"])
 def test_every_mode_leaves_the_files_of_delta_flushes(tmp_path, mode):
-    """"full" marks every block, which merges as one copy of the whole
-    image, and "auto" merges the arena's own diff: the files equal those of
-    delta flushes, and the live values."""
+    """"full" rewrites every object whole: the files equal those of delta
+    flushes, and the live values."""
     def run(m):
         d = tmp_path / m
         mgr = EasyCrashManager(NVMArena(backing_dir=str(d)),
-                               FlushPolicy(leaves=("k",), every_steps=1, async_flush=False,
-                                           persist_mode=m))
+                               FlushPolicy(leaves=("k",), every_steps=1, persist_mode=m))
         for step in (1, 2, 6):
             mgr.maybe_flush(step, {"k": _kv(step)})
-        mgr.close()
         return {f: (d / f).read_bytes() for f in sorted(os.listdir(d))}
 
     assert run(mode) == run("delta")
@@ -384,20 +441,19 @@ def test_a_value_in_another_memory_order_is_staged_row_major(tmp_path, layout, m
     is staged row-major once, so its image is merged in place and its files
     are those of the same values in row-major order."""
     staged = []
-    real_flush = NVMArena.flush
-    monkeypatch.setattr(NVMArena, "flush", lambda self, name, live, *a, **k: staged.append(
-        live.flags.c_contiguous) or real_flush(self, name, live, *a, **k))
+    for method in ("flush", "rewrite"):
+        real = getattr(NVMArena, method)
+        monkeypatch.setattr(NVMArena, method, lambda self, name, live, *a, real=real:
+                            staged.append(live.flags.c_contiguous) or real(self, name, live, *a))
 
     def run(d, order):
         mgr = EasyCrashManager(NVMArena(backing_dir=str(d)),
-                               FlushPolicy(leaves=("k",), every_steps=1, async_flush=False,
-                                           persist_mode="delta"))
+                               FlushPolicy(leaves=("k",), every_steps=1, persist_mode="delta"))
         images = []
         for step in (1, 2, 5):
             live = order(_kv(step))
             mgr.maybe_flush(step, {"k": live})
             images.append(mgr.arena.peek("k"))
-        mgr.close()
         assert all(img is images[0] for img in images)
         assert images[0].flags.c_contiguous
         return {f: (d / f).read_bytes() for f in sorted(os.listdir(d))}
