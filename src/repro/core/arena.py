@@ -18,7 +18,7 @@ from typing import Dict, Iterable, Optional
 import numpy as np
 
 from ..telemetry import span
-from .blocks import DEFAULT_BLOCK_BYTES, mix_blocks_into, obj_num_blocks
+from .blocks import DEFAULT_BLOCK_BYTES, obj_num_blocks, put_blocks, take_blocks
 from .durable import durable_replace
 
 
@@ -77,20 +77,38 @@ class NVMArena:
         Returns the number of blocks written.
         """
         live_value = np.asarray(live_value)
-        cur = self._store.get(name)
-        if cur is None or cur.nbytes != live_value.nbytes:
-            raise ValueError(f"{name!r}: no image of {live_value.nbytes} bytes to merge "
-                             "into; a first or resized flush is a rewrite")
+        cur = self._image(name, live_value.nbytes)
         mask = np.asarray(mask, dtype=bool)
         nb = obj_num_blocks(cur, self.block_bytes)
         if mask.shape != (nb,):
             raise ValueError(f"{name!r}: mask of shape {mask.shape} for {nb} blocks")
-        written = int(np.count_nonzero(mask))
-        if written:
-            with span("arena.mix", object=name, blocks=written):
-                mix_blocks_into(cur, live_value, mask, self.block_bytes)
+        idx = np.flatnonzero(mask)
+        return self.merge(name, idx, take_blocks(live_value, idx, self.block_bytes), idx.size)
+
+    def merge(self, name: str, idx: np.ndarray, rows: np.ndarray, blocks: int) -> int:
+        """Merge rows of bytes into the object's image, in place, and persist
+        the image if any moved: row ``i`` of ``rows`` (``uint8``, each a whole
+        number of blocks wide) covers the image's bytes from row index
+        ``idx[i]``; rows past ``len(idx)`` are ignored
+        (:func:`~repro.core.blocks.put_blocks`). ``blocks`` is the number of
+        dirty blocks the rows carry, which is counted and returned."""
+        cur = self._image(name)
+        if idx.size:
+            with span("arena.mix", object=name, blocks=int(blocks)):
+                put_blocks(cur, idx, rows, rows.shape[1])
             self._persist_to_backing(name)
-        return written
+        return int(blocks)
+
+    def _image(self, name: str, nbytes: Optional[int] = None) -> np.ndarray:
+        """The image a masked flush merges into: it must exist, at ``nbytes``
+        if given; a first flush, or an object that changed size, is a
+        :meth:`rewrite`."""
+        cur = self._store.get(name)
+        if cur is None or (nbytes is not None and cur.nbytes != nbytes):
+            size = "" if nbytes is None else f" of {nbytes} bytes"
+            raise ValueError(f"{name!r}: no image{size} to merge into; "
+                             "a first or resized flush is a rewrite")
+        return cur
 
     def rewrite(self, name: str, live_value: np.ndarray) -> int:
         """Persistence operation that writes every block of the object: for

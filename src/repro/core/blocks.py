@@ -37,6 +37,51 @@ def _as_byte_view(arr: np.ndarray) -> np.ndarray:
     return a.view(np.uint8).reshape(-1)
 
 
+def take_blocks(src: np.ndarray, idx: np.ndarray,
+                block_bytes: int = DEFAULT_BLOCK_BYTES) -> np.ndarray:
+    """The blocks ``idx`` (ascending) of ``src``'s bytes as ``uint8`` rows of
+    ``(len(idx), block_bytes)``; a partial final block is zero-padded."""
+    s = _as_byte_view(src)
+    full = s.size // block_bytes
+    rows = np.empty((idx.size, block_bytes), np.uint8)
+    k = idx.size
+    if k and idx[-1] == full:  # the partial final block
+        k -= 1
+        rows[k] = 0
+        rows[k, :s.size - full * block_bytes] = s[full * block_bytes:]
+    np.take(s[:full * block_bytes].reshape(full, block_bytes), idx[:k], axis=0, out=rows[:k])
+    return rows
+
+
+def put_blocks(
+    dst: np.ndarray,
+    idx: np.ndarray,
+    rows: np.ndarray,
+    block_bytes: int = DEFAULT_BLOCK_BYTES,
+) -> None:
+    """In-place blockwise merge, the one way blocks enter an image: write row
+    ``i`` of ``rows`` (``uint8``, ``block_bytes`` wide) into block ``idx[i]``
+    of ``dst``, which must be C-contiguous and writable. ``idx`` ascends;
+    rows past ``len(idx)`` (a gather's padding) are ignored, and a partial
+    final block takes the head of its row."""
+    if not (dst.flags.c_contiguous and dst.flags.writeable):
+        raise ValueError("put_blocks needs a C-contiguous, writable destination")
+    nb = obj_num_blocks(dst, block_bytes)
+    if rows.ndim != 2 or rows.shape[0] < idx.size or rows.shape[1] != block_bytes:
+        raise ValueError(f"{idx.size} blocks of {block_bytes} bytes from rows {rows.shape}")
+    if idx.size == 0:
+        return
+    if idx[0] < 0 or idx[-1] >= nb:
+        raise ValueError(f"block index out of range for {nb} blocks")
+    d = dst.reshape(-1).view(np.uint8)
+    full = d.size // block_bytes
+    k = idx.size
+    if idx[-1] == full:  # the partial final block
+        k -= 1
+        d[full * block_bytes:] = rows[k, :d.size - full * block_bytes]
+    d[:full * block_bytes].reshape(full, block_bytes)[idx[:k]] = rows[:k]
+
+
 def mix_blocks_into(
     dst: np.ndarray,
     src: np.ndarray,
@@ -46,9 +91,8 @@ def mix_blocks_into(
     """In-place blockwise merge: copy the blocks ``new_block_mask`` marks from
     ``src`` into ``dst``, which must be C-contiguous and writable.
 
-    Whole blocks move as rows of ``(n_full_blocks, block_bytes)``; a dirty
-    partial final block is copied on its own; a mask with every block set is
-    one copy of the whole image.
+    The marked blocks move as rows (:func:`take_blocks`, :func:`put_blocks`);
+    a mask with every block set is one copy of the whole image.
     """
     src = np.asarray(src)
     if dst.shape != src.shape or dst.dtype != src.dtype:
@@ -60,19 +104,10 @@ def mix_blocks_into(
     if not (dst.flags.c_contiguous and dst.flags.writeable):
         raise ValueError("mix_blocks_into needs a C-contiguous, writable destination")
     idx = np.flatnonzero(mask)
-    if idx.size == 0:
+    if 0 < idx.size == nb:
+        np.copyto(dst.reshape(-1).view(np.uint8), _as_byte_view(src))
         return
-    d = dst.reshape(-1).view(np.uint8)
-    s = _as_byte_view(src)
-    if idx.size == nb:
-        np.copyto(d, s)
-        return
-    full = d.size // block_bytes
-    if idx[-1] == full:  # the partial final block
-        d[full * block_bytes:] = s[full * block_bytes:]
-        idx = idx[:-1]
-    rows = full * block_bytes
-    d[:rows].reshape(full, block_bytes)[idx] = s[:rows].reshape(full, block_bytes)[idx]
+    put_blocks(dst, idx, take_blocks(src, idx, block_bytes), block_bytes)
 
 
 def mix_blocks(

@@ -10,6 +10,10 @@ This is the framework-facing layer: given a train-state pytree and a
   Pallas kernel) or whole (every block: in ``"full"`` mode, and for objects
   that every step rewrites whole, such as a model's recurrent state, named
   by the state's layout, not by a user);
+* masks a device value on the device, against a device copy of its last
+  flushed image, and brings only the dirty blocks to the host; a value
+  written whole crosses once, flattened on the device so that it arrives
+  row-major;
 * takes full coordinated checkpoints at the Young interval stretched by the
   measured recomputability (MTBF' = MTBF / (1 - R));
 * on restart, tries the EasyCrash path (arena image + acceptance
@@ -20,14 +24,18 @@ has zero cross-host traffic, so it scales to arbitrarily many nodes.
 """
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..telemetry import span, tracing
 from .arena import NVMArena
-from .blocks import obj_num_blocks
+from .blocks import num_blocks
 from .efficiency import young_interval
 
 
@@ -41,15 +49,16 @@ def _cast_like(img: np.ndarray, target: np.ndarray) -> np.ndarray:
     return img.astype(target.dtype)
 
 
-def flatten_state(state: Mapping[str, Any], prefix: str = "") -> Dict[str, np.ndarray]:
-    """Flatten a nested dict pytree of arrays into 'a/b/c' -> ndarray."""
-    out: Dict[str, np.ndarray] = {}
+def flatten_state(state: Mapping[str, Any], prefix: str = "") -> Dict[str, Any]:
+    """Flatten a nested dict pytree of arrays into 'a/b/c' -> array: device
+    arrays stay where they are, anything else becomes an ndarray."""
+    out: Dict[str, Any] = {}
     for k, v in state.items():
         key = f"{prefix}{k}"
         if isinstance(v, Mapping):
             out.update(flatten_state(v, key + "/"))
         else:
-            out[key] = np.asarray(v)
+            out[key] = v if isinstance(v, jax.Array) else np.asarray(v)
     return out
 
 
@@ -62,6 +71,58 @@ def unflatten_state(flat: Mapping[str, np.ndarray]) -> Dict[str, Any]:
             d = d.setdefault(p, {})
         d[parts[-1]] = v
     return out
+
+
+#: lanes of a TPU tile: a row of the dirty-block gather is the fewest whole
+#: blocks across them, so rows move whole tiles
+LANES = 128
+
+
+@jax.jit
+def _flat_copy(x):
+    """``x`` flat and row-major, in its dtype, in a buffer of its own: never
+    the caller's, which a later donating call may delete. Fetched, it
+    arrives row-major whatever the device's layout of ``x``."""
+    return jnp.array(x.reshape(-1), copy=True)
+
+
+@jax.jit
+def _flat_bytes(x):
+    """``x``'s bytes, as :func:`_flat_copy` gives its elements: the device
+    copy of an object masked on the device, so that its mask runs the
+    program the host mask compiles for an object of that size."""
+    # through rows of lanes: the TPU's compiler lays that out far faster than one long axis
+    flat = x.reshape(-1, LANES) if x.size % LANES == 0 else x.reshape(-1)
+    if x.dtype == jnp.bool_:
+        return flat.astype(jnp.uint8).reshape(-1)
+    return jnp.array(jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1), copy=True)
+
+
+@functools.partial(jax.jit, static_argnames=("block_elems",))
+def _merge_rows(image, live, idx, marked, *, block_elems: int):
+    """Rows ``idx`` of the flat ``image``, each block that ``marked`` (a flag
+    per block of each row) sets taken from the flat ``live``: the merged rows
+    for the host, and ``image`` with them set, which then holds the marked
+    blocks and no other change."""
+    row = marked.shape[1] * block_elems
+    n_rows = -(-live.size // row)
+    pad = n_rows * row - live.size
+
+    def rows(a):
+        return jnp.pad(a, (0, pad)).reshape(n_rows, row)
+
+    old = rows(image)
+    merged = jnp.where(jnp.repeat(marked, block_elems, axis=1), rows(live)[idx], old[idx])
+    # the rows stay rows: flattened, the TPU's compiler lays them out anew
+    return merged, old.at[idx].set(merged).reshape(-1)[:live.size]
+
+
+def _on_device(arr) -> bool:
+    """Whether ``arr`` is a device array whose bytes the device can view: of
+    a numeric or bool dtype."""
+    return isinstance(arr, jax.Array) and (
+        arr.dtype == jnp.bool_ or jnp.issubdtype(arr.dtype, jnp.integer)
+        or jnp.issubdtype(arr.dtype, jnp.floating))
 
 
 @dataclass
@@ -126,6 +187,10 @@ class EasyCrashManager:
         self.checkpoint_save = checkpoint_save
         self.checkpoint_restore = checkpoint_restore
         self.stats = ManagerStats()
+        #: per object masked on the device: a flat device copy of its arena
+        #: image's bytes, and the image it copies (a cache, rebuilt from the arena
+        #: where the image is no longer that array, as after a reattach)
+        self._images: Dict[str, Tuple[jax.Array, np.ndarray]] = {}
         # checkpoint cadence in *steps*, from Young's formula on the stretched
         # MTBF (paper §7); None disables periodic checkpoints.
         self.checkpoint_every: Optional[int] = None
@@ -158,48 +223,104 @@ class EasyCrashManager:
             return False
         with span("flush", step=step, mode=self.policy.persist_mode):
             with span("flush.stage") as stage:
-                flat = flatten_state(state)
-                sel = self._selected(flat)
+                sel = self._selected(flatten_state(state))
                 sel["__step__"] = np.asarray(step, dtype=np.int64)
-                # row-major, as the arena's blocks are: an array fetched from a
-                # device may come in another order, which every later byte view
-                # (mask, merge, file) would otherwise copy again to reorder
-                payload = {k: np.array(v, copy=True, order="C") for k, v in sel.items()}
+                # row-major, as the arena's blocks are: a host array in another
+                # order is copied once here, not again in every byte view (mask,
+                # merge, file); a device value is flattened on the device
+                payload = {k: v if isinstance(v, jax.Array) or v.flags.c_contiguous
+                           else np.array(v, order="C") for k, v in sel.items()}
                 if tracing():
-                    stage.add(nbytes=sum(v.nbytes for v in payload.values()))
+                    stage.add(nbytes=sum(v.nbytes for k, v in payload.items()
+                                         if v is not sel[k]))
             self._flush_now(step, payload)
         self.stats.flushes_issued += 1
         return True
 
-    def _flush_now(self, step: int, payload: Mapping[str, np.ndarray]) -> None:
+    def _flush_now(self, step: int, payload: Mapping[str, Any]) -> None:
         """Write each object of ``payload`` into the arena: the one place that
-        decides whether an object is written whole or masked."""
+        decides whether an object is written whole or masked, and where."""
         from . import delta_persist  # looked up per call, so it can be wrapped
 
         block = self.arena.block_bytes
         file_bytes = self.arena.file_bytes
         full = self.policy.persist_mode == "full"
         for name, arr in payload.items():
-            blocks = obj_num_blocks(arr, block)
+            blocks = num_blocks(arr.nbytes, block)
+            prior = self._images.pop(name, None)  # set again once the arena has the flush
             if full or any(self._match(name, leaf) for leaf in self.rewritten):
                 with span("flush.whole", object=name, nbytes=arr.nbytes, blocks=blocks):
-                    self.stats.blocks_written += self.arena.rewrite(name, arr)
+                    self.stats.blocks_written += self.arena.rewrite(
+                        name, self._fetch(name, arr, as_bytes=False)[0])
                 continue
             cur = self.arena.peek(name)
-            mask = None
+            resized = cur is None or cur.nbytes != arr.nbytes
+            on_device = not resized and _on_device(arr)
             with span("flush.mask", object=name, nbytes=arr.nbytes,
                       block_bytes=block) as s:
-                if cur is not None and cur.nbytes == arr.nbytes:
-                    mask = delta_persist.delta_block_mask(cur, arr, block)
+                if on_device:
+                    image = prior[0] if prior is not None and prior[1] is cur else \
+                        jax.device_put(cur.reshape(-1).view(np.uint8), may_alias=False)
+                    live = _flat_bytes(arr)
+                    mask = delta_persist.delta_block_mask(image, live, block)
+                    idx, rows, image = self._fetch_rows(name, image, live, mask, block)
+                else:  # a first flush, a resized object, or a host value
+                    value, flat = self._fetch(name, arr, as_bytes=True)
+                    mask = None if resized else delta_persist.delta_block_mask(cur, value, block)
+                dirty = blocks if mask is None else int(np.count_nonzero(mask))
                 if tracing():
-                    dirty = blocks if mask is None else int(np.count_nonzero(mask))
                     s.add(blocks=blocks, dirty_blocks=dirty)
-            if mask is None:  # a first flush, or an object that changed size
-                self.stats.blocks_written += self.arena.rewrite(name, arr)
+            if on_device:
+                self.stats.blocks_written += self.arena.merge(name, idx, rows, dirty)
+            elif mask is None:
+                self.stats.blocks_written += self.arena.rewrite(name, value)
+                image = flat if _on_device(arr) else None
             else:
-                self.stats.blocks_written += self.arena.flush(name, arr, mask)
+                self.stats.blocks_written += self.arena.flush(name, value, mask)
+                image = None
+            if image is not None:
+                self._images[name] = (image, self.arena.peek(name))
         self.arena.save_manifest()
         self.stats.bytes_written += self.arena.file_bytes - file_bytes
+
+    @staticmethod
+    def _fetch(name: str, arr, as_bytes: bool) -> Tuple[np.ndarray, Optional[jax.Array]]:
+        """The value on the host, row-major, and for a device value its flat
+        device copy (its bytes where the copy is kept as the object's device
+        image): fetched whole, in one copy (``flush.fetch``)."""
+        if not _on_device(arr):
+            return np.asarray(arr), None
+        with span("flush.fetch", object=name, nbytes=arr.nbytes, whole=1):
+            flat = (_flat_bytes if as_bytes else _flat_copy)(arr)
+            return np.asarray(flat).view(arr.dtype).reshape(arr.shape), flat
+
+    @staticmethod
+    def _fetch_rows(name: str, image: jax.Array, live: jax.Array, mask: np.ndarray,
+                    block: int):
+        """Merge the blocks the mask marks from the flat device bytes ``live``
+        into the device ``image``, and fetch the rows that hold them: the
+        rows' indices, their bytes on the host (``uint8``, one row of
+        ``k`` blocks each, any rows past the indices a gather's padding), and
+        the new device image. A row is the fewest blocks that fill whole
+        lanes; the gather's length is the dirty row count rounded up to a power
+        of two (at most every row), so a session compiles it once per size
+        (``flush.fetch``)."""
+        block_elems = block // live.dtype.itemsize
+        k = math.lcm(block_elems, LANES) // block_elems
+        n_rows = -(-mask.size // k)
+        by_row = np.pad(mask, (0, n_rows * k - mask.size)).reshape(n_rows, k)
+        dirty = np.flatnonzero(by_row.any(axis=1))
+        with span("flush.fetch", object=name, whole=0) as f:
+            rows = np.zeros((0, k * block), np.uint8)
+            if dirty.size:
+                idx = np.full(min(1 << (dirty.size - 1).bit_length(), n_rows), dirty[-1], np.int32)
+                idx[:dirty.size] = dirty
+                merged, image = _merge_rows(image, live, idx, by_row[idx], block_elems=block_elems)
+                rows = np.ascontiguousarray(merged).view(np.uint8).reshape(idx.size, k * block)
+                image.block_until_ready()  # no device read outlives the flush
+            if tracing():
+                f.add(nbytes=rows.nbytes + mask.nbytes, rows=len(rows))
+        return dirty, rows, image
 
     # ------------------------------------------------------------- checkpoint
     def maybe_checkpoint(self, step: int, state: Mapping[str, Any]) -> bool:
@@ -228,7 +349,7 @@ class EasyCrashManager:
         Returns (state, step, source) with source in
         {"easycrash", "checkpoint", "fresh"}.
         """
-        flat_init = flatten_state(init_state)
+        flat_init = {k: np.asarray(v) for k, v in flatten_state(init_state).items()}
         # --- EasyCrash path: arena image over init state
         names = set(self.arena.names())
         if "__step__" in names:

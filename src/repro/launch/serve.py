@@ -120,15 +120,20 @@ def _serve(args, session: span) -> Dict[str, float]:
 
 
 def _to_host(all_tokens, cache=None) -> Dict[str, object]:
-    """The decode cache, if given, and the token buffer on the host. The
-    buffer's pieces are joined there: a join on the device would compile
-    anew for every length."""
+    """The token buffer on the host, joined there (a join on the device
+    would compile anew for every length), and the decode cache, if given,
+    as device copies of its own, which the next step's donation leaves
+    alone: a flush brings to the host only what it writes."""
+    out = {} if cache is None else {"cache": _copy(cache)}
     with span("serve.host_copy") as copy:
-        host = {} if cache is None else {"cache": jax.tree.map(np.asarray, cache)}
-        host["tokens"] = np.concatenate(jax.device_get(all_tokens), axis=1)
+        out["tokens"] = np.concatenate(jax.device_get(all_tokens), axis=1)
         if tracing():
-            copy.add(nbytes=sum(a.nbytes for a in jax.tree.leaves(host)))
-    return host
+            copy.add(nbytes=out["tokens"].nbytes)
+    return out
+
+
+#: one program that copies the whole decode cache on the device
+_copy = jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
 
 
 def _cache_bytes(cache, rewritten) -> Dict[str, int]:

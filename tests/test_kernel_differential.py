@@ -183,6 +183,50 @@ def test_delta_block_mask_matches_cpu_reference(n, dtype):
         assert not clean.any()
 
 
+@pytest.mark.parametrize("nbytes", [4, 8, 64 * 129 - 5])
+def test_the_mask_of_host_zeros_is_clean(nbytes):
+    """The benchmark's warm-up call: host uint8 zeros give every block
+    clean, one host flag a block."""
+    zero = np.zeros(nbytes, np.uint8)
+    got = delta_block_mask(zero, zero, 64)
+    assert got.dtype == bool and got.shape == (-(-nbytes // 64),) and not got.any()
+
+
+@pytest.mark.parametrize("dtype", [np.float32, "bfloat16"])
+@pytest.mark.parametrize("n", [1, 7, 257, 4097])
+def test_a_device_value_is_masked_as_its_bytes_differ(n, dtype):
+    """Flat device values in their own dtype give the host byte mask."""
+    if dtype == "bfloat16":
+        dtype = jnp.bfloat16.dtype
+    series = _persist_series(n, dtype, np.random.default_rng(n + 2))
+    for cur, live in zip(series, series[1:]):
+        got = delta_block_mask(jnp.asarray(cur), jnp.asarray(live), block_bytes=64)
+        np.testing.assert_array_equal(got, block_diff_mask(cur, live, block_bytes=64))
+        assert got.dtype == bool
+        assert not delta_block_mask(jnp.asarray(live), jnp.asarray(live), 64).any()
+
+
+def test_the_trainers_host_flushes_take_the_byte_masks(tmp_path, monkeypatch):
+    """``launch/train.py`` hands the manager host state: its masks are
+    computed on host bytes and are the CPU reference's."""
+    from repro.core import delta_persist
+    from repro.launch import train
+
+    seen = []
+    real = delta_persist.delta_block_mask
+
+    def check(cur, live, block_bytes=64):
+        got = real(cur, live, block_bytes)
+        seen.append(isinstance(cur, np.ndarray) and isinstance(live, np.ndarray)
+                    and np.array_equal(got, block_diff_mask(cur, live, block_bytes)))
+        return got
+
+    monkeypatch.setattr(delta_persist, "delta_block_mask", check)
+    train.main(["--steps", "6", "--flush-every", "2", "--width", "32", "--seq", "16",
+                "--batch", "2", "--log-every", "100", "--workdir", str(tmp_path)])
+    assert len(seen) > 2 and all(seen)
+
+
 # ------------------------------------------------------------------ rwkv6
 def _rwkv_inputs(b, t, h, d, dtype, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)

@@ -462,3 +462,152 @@ def test_a_value_in_another_memory_order_is_staged_row_major(tmp_path, layout, m
     assert not _in_order(_kv(1), layout).flags.c_contiguous
     assert other == run(tmp_path / "C", lambda a: a)
     assert len(staged) == 12 and all(staged)  # k and the step, 3 flushes, 2 runs
+
+
+# ------------------------------- device values: masked where they live
+def _device_state(step):
+    """A decode cache on the device: bf16 K and V gaining one position (one
+    block) a step, the position counter, a float32 object whose only block
+    is partial, and a recurrent state that every step rewrites."""
+    import jax.numpy as jnp
+
+    return {"cache": {"k": jnp.asarray(_kv(step)), "v": jnp.asarray(-_kv(step)),
+                      "t": jnp.asarray(step, jnp.int32),
+                      "odd": jnp.arange(7, dtype=jnp.float32) * step,
+                      "ssm": jnp.full((3, 5), step, jnp.float32)}}
+
+
+DEVICE_MASKED = ["cache/k", "cache/odd", "cache/t", "cache/v"]
+
+
+def _device_manager(arena):
+    return EasyCrashManager(arena, FlushPolicy(leaves=("cache",)), rewritten=("cache/ssm",))
+
+
+def _assert_images_are_the_arenas(mgr):
+    assert sorted(mgr._images) == DEVICE_MASKED
+    for name, (image, _) in mgr._images.items():
+        assert np.asarray(image).tobytes() == mgr.arena.peek(name).tobytes(), name
+
+
+@pytest.mark.parametrize("mask", ["kernel", "nothing dirty"])
+def test_the_device_image_is_the_arena_image_after_every_flush(tmp_path, mask, monkeypatch):
+    import jax
+
+    from repro.core import delta_persist
+
+    masks = []
+    real = delta_persist.delta_block_mask
+
+    def chosen(cur, live, block_bytes=64):
+        got = real(cur, live, block_bytes)
+        masks.append(isinstance(live, jax.Array))
+        return got if mask == "kernel" else np.zeros_like(got)
+
+    monkeypatch.setattr(delta_persist, "delta_block_mask", chosen)
+    mgr = _device_manager(NVMArena(backing_dir=str(tmp_path)))
+    for step in (1, 2, 5):
+        mgr.maybe_flush(step, _device_state(step))
+        _assert_images_are_the_arenas(mgr)
+    # the later flushes masked the four objects on the device, the step on the host
+    assert masks == [True] * 4 + [False] + [True] * 4 + [False]
+    kept = 5 if mask == "kernel" else 1  # a mask that marks nothing leaves the first flush
+    for name, value in flatten_state(_device_state(kept)).items():
+        if name != "cache/ssm":
+            assert mgr.arena.peek(name).tobytes() == np.asarray(value).tobytes(), name
+    assert mgr.arena.peek("cache/ssm").tobytes() == np.asarray(_device_state(5)["cache"]["ssm"]).tobytes()
+
+
+@pytest.mark.parametrize("how", ["reattach", "install"])
+def test_the_device_image_is_rebuilt_from_an_arena_image_it_did_not_write(
+        tmp_path, how, monkeypatch):
+    """After a reattach (the resume path), or an image installed in the
+    arena, the first masked flush masks against the arena's image."""
+    import jax
+
+    from repro.core import delta_persist
+
+    mgr = _device_manager(NVMArena(backing_dir=str(tmp_path / "crashed")))
+    whole = _device_manager(NVMArena(backing_dir=str(tmp_path / "whole")))
+    for step in (1, 2, 5):
+        whole.maybe_flush(step, _device_state(step))
+    for step in (1, 2):
+        mgr.maybe_flush(step, _device_state(step))
+    at = flatten_state(_device_state(2))
+    if how == "reattach":
+        mgr = _device_manager(NVMArena.reattach(str(tmp_path / "crashed")))
+        assert not mgr._images
+    else:
+        at["cache/k"] = _kv(4)
+        mgr.arena.install("cache/k", at["cache/k"])
+    compared = []
+    real = delta_persist.delta_block_mask
+
+    def record(cur, live, block_bytes=64):
+        if isinstance(cur, jax.Array):
+            compared.append(np.asarray(cur).tobytes())
+        return real(cur, live, block_bytes)
+
+    monkeypatch.setattr(delta_persist, "delta_block_mask", record)
+    mgr.maybe_flush(5, _device_state(5))
+    # each device object was masked against its image as the arena held it
+    assert compared == [np.asarray(at[n]).tobytes()
+                        for n in ("cache/k", "cache/v", "cache/t", "cache/odd")]
+    _assert_images_are_the_arenas(mgr)
+    for f in sorted(os.listdir(tmp_path / "whole")):
+        assert (tmp_path / "crashed" / f).read_bytes() == (tmp_path / "whole" / f).read_bytes(), f
+
+
+def test_no_buffer_the_manager_keeps_is_deleted_by_a_donating_step(tmp_path):
+    """The state handed to a flush is the state the next step donates: the
+    manager keeps copies of its own, and no read of the flush outlives it."""
+    import jax
+
+    advance = jax.jit(lambda s: jax.tree.map(lambda a: a + 1, s), donate_argnums=0)
+    mgr = _device_manager(NVMArena(backing_dir=str(tmp_path)))
+    state = _device_state(1)
+    for step in (1, 2, 3):
+        mgr.maybe_flush(step, state)
+        handed = jax.tree.leaves(state)
+        state = advance(state)
+        assert all(a.is_deleted() for a in handed)  # the step did donate them
+        assert not any(image.is_deleted() for image, _ in mgr._images.values())
+        _assert_images_are_the_arenas(mgr)
+    mgr.maybe_flush(4, state)
+    for name, value in flatten_state(state).items():
+        assert mgr.arena.peek(name).tobytes() == np.asarray(value).tobytes(), name
+
+
+SESSION = ["--width", "64", "--prompts", "2", "--prompt-len", "8", "--decode-steps", "12",
+           "--flush-every", "4", "--seed", "2147483651"]
+
+
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "granite-4.0-h-micro"])
+def test_a_delta_session_writes_the_files_of_a_full_one_and_of_host_values(
+        tmp_path, arch, monkeypatch):
+    """The server's cache, masked on the device, leaves the arena files of a
+    session that writes it whole, and of one that hands the manager host
+    copies of the same cache (the host mask)."""
+    import jax
+
+    from repro.core import delta_persist
+    from repro.launch import serve
+
+    on_device = []
+    real = delta_persist.delta_block_mask
+    monkeypatch.setattr(delta_persist, "delta_block_mask", lambda c, l, b=64: on_device.append(
+        isinstance(l, jax.Array)) or real(c, l, b))
+
+    def files(run, mode="delta"):
+        serve.main(["--arch", arch, *SESSION, "--persist-mode", mode,
+                    "--workdir", str(tmp_path / run)])
+        d = tmp_path / run / "serve_arena"
+        return {f: (d / f).read_bytes() for f in sorted(os.listdir(d))}
+
+    delta = files("delta")
+    assert any(on_device)
+    assert delta == files("full")
+    on_device.clear()
+    monkeypatch.setattr(serve, "_copy", lambda cache: jax.tree.map(np.asarray, cache))
+    assert delta == files("host")
+    assert on_device and not any(on_device)

@@ -14,13 +14,14 @@ from repro.configs import get_arch
 from repro.core import NVMArena
 from repro.core.manager import EasyCrashManager, FlushPolicy
 from repro.launch import serve
-from repro.models import init_cache, scaled_down
+from repro.models import scaled_down
 from repro.telemetry import span, tracing
 
 WIDTH, PROMPTS, PROMPT_LEN, STEPS, EVERY = 64, 2, 8, 12, 4
 FLUSHES = STEPS // EVERY
 KV = ("cache/group0/pos0/k", "cache/group0/pos0/v")
 OBJECTS = (*KV, "cache/t", "tokens", "__step__")
+DEVICE = (*KV, "cache/t")
 
 
 def _serve(workdir, mode="delta", every=EVERY):
@@ -92,6 +93,9 @@ def _files_received(arena_dir):
     ("serve.decode", STEPS), ("serve.host_copy", FLUSHES + 1),
     ("flush", FLUSHES), ("flush.stage", FLUSHES),
     ("flush.mask", FLUSHES * len(OBJECTS)), ("arena.write", FLUSHES * len(OBJECTS)),
+    # the cache's objects on the device, K, V and the position: fetched whole
+    # at the first flush, their dirty blocks at each later one
+    ("flush.fetch", FLUSHES * len(DEVICE)),
     ("arena.fsync", FLUSHES * len(OBJECTS)), ("arena.rename", FLUSHES * len(OBJECTS)),
     ("arena.manifest", FLUSHES),
     # later flushes mix the dirty blocks in; the growing token buffer is
@@ -118,10 +122,10 @@ def test_spans_carry_their_stats(sessions):
     assert [d[4]["step"] for d in _named(sessions, "serve.decode")] == list(range(1, STEPS + 1))
     assert [f[4] for f in _named(sessions, "flush")] == [
         {"step": EVERY * k, "mode": "delta"} for k in range(1, FLUSHES + 1)]
-    cache = sum(a.nbytes for a in jax.tree.leaves(
-        init_cache(_cfg(), PROMPTS, PROMPT_LEN + STEPS + 1)))
+    # the server copies the token buffer alone to the host: the flush fetches
+    # what it writes of the cache (flush.fetch)
     copies = [c[4]["nbytes"] for c in _named(sessions, "serve.host_copy")]
-    assert copies == [cache + _token_bytes(EVERY * k) for k in range(1, FLUSHES + 1)] + [
+    assert copies == [_token_bytes(EVERY * k) for k in range(1, FLUSHES + 1)] + [
         _token_bytes(STEPS)]
 
 
@@ -147,6 +151,7 @@ def test_write_counter_is_what_the_file_received(sessions, obj):
 
 @pytest.mark.parametrize("inner,outer", [
     ("arena.write", "flush"), ("flush.mask", "flush"), ("flush", "serve.session"),
+    ("flush.fetch", "flush.mask"),
     ("serve.decode", "serve.session"), ("serve.host_copy", "serve.session"),
 ])
 def test_spans_nest_on_their_thread(sessions, inner, outer):
@@ -157,6 +162,71 @@ def test_spans_nest_on_their_thread(sessions, inner, outer):
 
 def _cfg():
     return scaled_down(get_arch("stablelm-1.6b"), width=WIDTH)
+
+
+@pytest.fixture(scope="module")
+def hybrid_spans(tmp_path_factory):
+    """A traced delta session of the hybrid: its recurrent state is written
+    whole at every flush, its K/V masked."""
+    root = tmp_path_factory.mktemp("telemetry_hybrid")
+    jax.profiler.start_trace(str(root / "trace"))
+    serve.main(["--arch", "granite-4.0-h-micro", "--width", str(WIDTH),
+                "--prompts", str(PROMPTS), "--prompt-len", str(PROMPT_LEN),
+                "--decode-steps", str(STEPS), "--flush-every", str(EVERY),
+                "--workdir", str(root / "run")])
+    jax.profiler.stop_trace()
+    return _read_spans(str(root / "trace"))
+
+
+def _fetches(spans):
+    """Each ``flush.fetch`` with the ``flush.mask`` or ``flush.whole`` span
+    of the same object around it on its thread (None where there is none)."""
+    flushes = [s for s in spans if s[0] in ("flush.mask", "flush.whole")]
+    return [(f, next((o for o in flushes if o[1] == f[1] and o[2] <= f[2] and f[3] <= o[3]
+                      and o[4]["object"] == f[4]["object"]), None))
+            for f in spans if f[0] == "flush.fetch"]
+
+
+@pytest.fixture(params=["stablelm", "granite"])
+def traced(request, sessions, hybrid_spans):
+    return sessions["spans"] if request.param == "stablelm" else hybrid_spans
+
+
+def test_each_fetch_lies_in_the_flush_span_of_its_object(traced):
+    fetches = _fetches(traced)
+    assert fetches and all(outer is not None for _, outer in fetches)
+    # every flush.whole of a device object holds one fetch, whole
+    whole = [s for s in traced if s[0] == "flush.whole"]
+    assert sorted(o[2] for _, o in fetches if o[0] == "flush.whole") == sorted(
+        s[2] for s in whole)
+    assert all(f[4]["whole"] == 1 for f, o in fetches if o[0] == "flush.whole")
+
+
+def _pow2(n):
+    return 0 if n == 0 else 1 << (n - 1).bit_length()
+
+
+def test_a_fetch_counts_the_bytes_it_brought(traced):
+    """Whole: the object's bytes. Masked: the rows that hold the dirty
+    blocks, each the fewest blocks that fill 128 lanes of bytes, their count
+    rounded up to a power of two (at most every row), and the mask, a byte a
+    block."""
+    for f, outer in _fetches(traced):
+        stats = outer[4]
+        if f[4]["whole"]:
+            assert f[4]["nbytes"] == stats["nbytes"]
+            assert outer[0] == "flush.whole" or stats["dirty_blocks"] == stats["blocks"]
+            continue
+        dirty, blocks = stats["dirty_blocks"], stats["blocks"]
+        row = 128  # the device masks and gathers an object's bytes
+        k, rows = row // stats["block_bytes"], f[4]["rows"]
+        n_rows = -(-blocks // k)
+        assert f[4]["nbytes"] == rows * row + blocks
+        # between the fewest and the most rows the dirty blocks can lie in
+        assert min(_pow2(-(-dirty // k)), n_rows) <= rows <= min(_pow2(dirty), n_rows)
+        assert rows == n_rows or rows == _pow2(rows)
+    # the hybrid's K/V are masked at its later flushes, its state never
+    assert any(not f[4]["whole"] for f, _ in _fetches(traced))
 
 
 def test_write_amplification_follows_from_the_shapes(sessions):
