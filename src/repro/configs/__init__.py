@@ -6,6 +6,7 @@ from .musicgen_medium import CONFIG as musicgen_medium
 from .minitron_8b import CONFIG as minitron_8b
 from .granite_8b import CONFIG as granite_8b
 from .granite_4_0_h_micro import CONFIG as granite_4_0_h_micro
+from .deepseek_v2_lite import CONFIG as deepseek_v2_lite
 from .stablelm_1_6b import CONFIG as stablelm_1_6b
 from .nemotron_4_340b import CONFIG as nemotron_4_340b
 from .recurrentgemma_9b import CONFIG as recurrentgemma_9b
@@ -28,6 +29,7 @@ ARCHS: Dict[str, ModelConfig] = {
         qwen2_moe_a2_7b,
         internvl2_76b,
         granite_4_0_h_micro,
+        deepseek_v2_lite,
     )
 }
 

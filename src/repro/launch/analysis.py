@@ -190,15 +190,21 @@ def roofline_from_compiled(
 
 # --------------------------------------------------------- model FLOP counts
 def param_counts(cfg) -> Dict[str, float]:
-    """Analytic parameter counts: total / active (MoE top-k) / embeddings."""
+    """Analytic parameter counts: total (of the experts, those held here) /
+    active (MoE top-k) / embeddings."""
     d, ff, V = cfg.d_model, cfg.d_ff, cfg.vocab
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     embed = V * d * (1 if cfg.tie_embeddings else 2)
     total = 0.0
     active = 0.0
-    for pattern, rep in cfg.groups:
+    for gi, (pattern, rep) in enumerate(cfg.groups):
         for kind in pattern:
-            if kind == "attn":
+            if kind == "attn" and cfg.mla:
+                a = cfg.mla
+                mix = (d * hq * a.qk_head_dim + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                       + a.kv_lora_rank * hq * (a.qk_nope_head_dim + a.v_head_dim)
+                       + hq * a.v_head_dim * d + a.kv_lora_rank)
+            elif kind == "attn":
                 mix = d * hq * dh + 2 * d * hkv * dh + hq * dh * d
             elif kind == "rec":
                 dr = cfg.rec.d_rnn
@@ -215,10 +221,10 @@ def param_counts(cfg) -> Dict[str, float]:
                        + 3 * heads + inner + inner * d)
             else:
                 mix = 0.0
-            if cfg.moe is not None and kind == "attn":
+            if cfg.group_uses_moe(gi):
                 m = cfg.moe
                 expert = 3 * d * m.d_ff_expert
-                routed_total = m.num_experts * expert
+                routed_total = m.experts_held * expert
                 routed_active = m.top_k * expert
                 shared = 3 * d * m.d_ff_shared if m.d_ff_shared else 0.0
                 router = d * m.num_experts

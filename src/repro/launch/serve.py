@@ -1,4 +1,5 @@
-"""Batched decode server with EasyCrash KV/recurrent-state persistence.
+"""Batched decode server with EasyCrash persistence of the decode cache (K/V,
+MLA's latent, recurrent state).
 
 Serves a (reduced-by-default) architecture: prefill a batch of prompts,
 decode greedily, and — the EasyCrash extension for inference — persist the
@@ -82,13 +83,15 @@ def _serve(args, session: span) -> Dict[str, float]:
         token = all_tokens[-1][:, -1:]
     else:
         start = 0
-        with span("serve.prefill"):
+        with span("serve.prefill") as pre:
             logits, cache = prefill_fn(params, {"tokens": prompts})
             # right-size the cache for continued decoding
             full_cache = init_cache(cfg, args.prompts, max_len)
             cache = _splice_cache(cfg, full_cache, cache, args.prompt_len)
             token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             all_tokens = [prompts, token]
+            if "moe" in cache and tracing():
+                pre.add(dropped=int(cache["moe"]["dropped"]))  # waits for the prefill
     session.add(**_cache_bytes(cache, rewritten))
 
     t0 = time.time()
@@ -106,6 +109,12 @@ def _serve(args, session: span) -> Dict[str, float]:
             raise SimulatedFailure(f"injected failure at decode step {step + 1}")
     dt = time.time() - t0
     out = _to_host(all_tokens)["tokens"]
+    expert = {}
+    if "moe" in cache:
+        # the pairs routed to the experts held here, counted on the device
+        expert = {"experts_held": cfg.moe.experts_held,
+                  "expert_pairs": int(cache["moe"]["pairs"])}
+        session.add(**expert)
     stats = {
         "decode_steps": args.decode_steps - start,
         "tokens_per_s": (args.decode_steps - start) * args.prompts / max(dt, 1e-9),
@@ -113,6 +122,7 @@ def _serve(args, session: span) -> Dict[str, float]:
         "bytes_written": mgr.stats.bytes_written,
         "resumed": resumed,
         "output_shape": list(out.shape),
+        **expert,
     }
     print("[done]", stats)
     stats["tokens"] = out
@@ -138,22 +148,29 @@ _copy = jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
 
 def _cache_bytes(cache, rewritten) -> Dict[str, int]:
     """The decode cache's bytes of recurrent state (the subtrees a step
-    rewrites whole) and of attention K/V (the rest, but the counter)."""
-    out = {"state_bytes": 0, "kv_bytes": 0}
+    rewrites whole), of MLA's latent (``c_kv`` and ``k_pe``) and of
+    attention K/V (the rest of the layers; not the counters)."""
+    out = {"state_bytes": 0, "latent_bytes": 0, "kv_bytes": 0}
     for g, layers in cache.items():
-        if g == "t":
+        if not g.startswith("group"):
             continue
         for pos, leaves in layers.items():
-            kind = "state_bytes" if f"cache/{g}/{pos}" in rewritten else "kv_bytes"
+            if f"cache/{g}/{pos}" in rewritten:
+                kind = "state_bytes"
+            elif "c_kv" in leaves:
+                kind = "latent_bytes"
+            else:
+                kind = "kv_bytes"
             out[kind] += sum(a.nbytes for a in jax.tree.leaves(leaves))
     return out
 
 
 def _splice_cache(cfg, full_cache, prefill_cache, prompt_len: int):
-    """Install prefill K/V into the right-sized decode cache."""
+    """Install the prefill's cache into the right-sized decode cache: K/V
+    and MLA's latent by position, the rest (state, counters) whole."""
     def splice(dst, src):
         if dst.ndim >= 3 and src.ndim == dst.ndim and dst.shape != src.shape:
-            # KV caches: (L, B, S, H, D) — copy the prefix
+            # KV caches (L, B, S, H, D), latent caches (L, B, S, C): copy the prefix
             n = min(src.shape[2], dst.shape[2])
             return jax.lax.dynamic_update_slice_in_dim(dst, src[:, :, :n], 0, axis=2)
         return src.astype(dst.dtype) if src.shape == dst.shape else dst

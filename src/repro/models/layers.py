@@ -1,6 +1,7 @@
 """Shared layers: norms, rotary embedding, MLPs, initializers."""
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -44,6 +45,45 @@ def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> Tuple[jax.
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     ang = positions[..., None].astype(jnp.float32) * freqs
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched ``factor`` times."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """Inverse frequencies of ``dim``-wide rotary pairs under YaRN
+    (:class:`~repro.models.config.YarnConfig`): the original frequencies for
+    pairs that turn more than ``beta_fast`` times over the original context,
+    those divided by ``factor`` for pairs that turn fewer than ``beta_slow``
+    times, and a linear ramp between the two pair indices (floored and
+    ceiled)."""
+    def pair_index(rotations: float) -> float:
+        return dim * math.log(yarn.original_max_position / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair_index(yarn.beta_fast)), 0)
+    high = min(math.ceil(pair_index(yarn.beta_slow)), dim - 1)
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) / max(high - low, 1e-3), 0, 1)
+    return (base / yarn.factor * ramp + base * (1 - ramp)).astype(np.float32)
+
+
+def rope_angles_interleaved(positions: jax.Array, inv_freq: np.ndarray):
+    """positions: (...,) -> (cos, sin) of shape (..., len(inv_freq))."""
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def apply_rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotary on adjacent pairs ``(x[2i], x[2i+1])`` (complex-number
+    pairing, as DeepSeek-V2 rotates). x: (..., S, H, D); cos/sin: (S, D/2)."""
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    c = cos[..., :, None, :]
+    s = sin[..., :, None, :]
+    return jnp.stack([a * c - b * s, a * s + b * c], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -4,11 +4,16 @@ Layer stacks compile as one ``lax.scan`` per *group* (a repeated pattern of
 layer kinds) with rematerialization, so the HLO stays one-layer-sized even
 for 96-layer models and the dry-run compiles quickly.  Per layer kind:
 
-  attn  — GQA attention (optionally local-window) + gated MLP (or MoE)
+  attn  — GQA attention (optionally local-window), or latent attention (MLA)
+          where the configuration has ``mla``, + gated MLP (or MoE)
   rec   — RG-LRU recurrence + gated MLP
   rwkv  — RWKV-6 time-mix + gated MLP (channel-mix swapped for SwiGLU of the
           same width; parameter-count equivalent — noted in DESIGN.md)
   mamba — Mamba-2 mixer (chunked SSD) + gated MLP
+
+A group's layers have experts in place of the dense MLP where the
+configuration says so (``ModelConfig.group_uses_moe``: every layer from
+``moe.first_k_dense`` on), whatever their kind.
 
 Granite's scalars (``embedding_multiplier``, ``residual_multiplier``,
 ``logits_scaling``) apply where the configuration sets them; at 1 they add
@@ -32,7 +37,13 @@ from .attention import (
     attn_params,
     attn_specs,
     init_kv_cache,
+    init_latent_cache,
     kv_cache_specs,
+    latent_cache_specs,
+    mla_decode,
+    mla_full,
+    mla_params,
+    mla_specs,
 )
 from .config import ModelConfig
 from .layers import dtype_of, mlp_apply, mlp_params, mlp_specs, normal_init, rms_norm
@@ -65,12 +76,8 @@ from .rwkv6 import (
 Params = Dict[str, Any]
 
 
-def _layer_uses_moe(cfg: ModelConfig, kind: str) -> bool:
-    return cfg.moe is not None and kind == "attn"
-
-
 # ------------------------------------------------------------------- params
-def _sublayer_params(cfg: ModelConfig, kind: str, key, n: int) -> Dict:
+def _sublayer_params(cfg: ModelConfig, kind: str, key, n: int, moe: bool) -> Dict:
     k_mix, k_ffn, k_norm = jax.random.split(key, 3)
     dt = dtype_of(cfg)
     p: Dict[str, Any] = {
@@ -78,7 +85,7 @@ def _sublayer_params(cfg: ModelConfig, kind: str, key, n: int) -> Dict:
         "norm2": jnp.zeros((n, cfg.d_model), dt),
     }
     if kind == "attn":
-        p["attn"] = attn_params(cfg, k_mix, n)
+        p["attn"] = mla_params(cfg, k_mix, n) if cfg.mla else attn_params(cfg, k_mix, n)
     elif kind == "rec":
         p["rec"] = rglru_params(cfg, k_mix, n)
     elif kind == "rwkv":
@@ -87,24 +94,24 @@ def _sublayer_params(cfg: ModelConfig, kind: str, key, n: int) -> Dict:
         p["mamba"] = mamba_params(cfg, k_mix, n)
     else:
         raise ValueError(kind)
-    if _layer_uses_moe(cfg, kind):
+    if moe:
         p["moe"] = moe_params(cfg, k_ffn, n)
     else:
         p["mlp"] = mlp_params(cfg, k_ffn, n)
     return p
 
 
-def _sublayer_specs(cfg: ModelConfig, kind: str, tp: int) -> Dict:
+def _sublayer_specs(cfg: ModelConfig, kind: str, tp: int, moe: bool) -> Dict:
     p: Dict[str, Any] = {"norm1": (None, None), "norm2": (None, None)}
     if kind == "attn":
-        p["attn"] = attn_specs(cfg, tp)
+        p["attn"] = mla_specs() if cfg.mla else attn_specs(cfg, tp)
     elif kind == "rec":
         p["rec"] = rglru_specs()
     elif kind == "rwkv":
         p["rwkv"] = rwkv_specs()
     elif kind == "mamba":
         p["mamba"] = mamba_specs()
-    if _layer_uses_moe(cfg, kind):
+    if moe:
         p["moe"] = moe_specs(cfg)
     else:
         p["mlp"] = mlp_specs()
@@ -125,7 +132,7 @@ def init_params(cfg: ModelConfig, key) -> Params:
     for gi, (pattern, rep) in enumerate(cfg.groups):
         gkeys = jax.random.split(keys[3 + gi], len(pattern))
         params[f"group{gi}"] = {
-            f"pos{pi}": _sublayer_params(cfg, kind, gkeys[pi], rep)
+            f"pos{pi}": _sublayer_params(cfg, kind, gkeys[pi], rep, cfg.group_uses_moe(gi))
             for pi, kind in enumerate(pattern)
         }
     return params
@@ -140,7 +147,7 @@ def param_specs(cfg: ModelConfig, tp: int = 16) -> Params:
         specs["unembed"] = ("fsdp", "vocab")
     for gi, (pattern, rep) in enumerate(cfg.groups):
         specs[f"group{gi}"] = {
-            f"pos{pi}": _sublayer_specs(cfg, kind, tp)
+            f"pos{pi}": _sublayer_specs(cfg, kind, tp, cfg.group_uses_moe(gi))
             for pi, kind in enumerate(pattern)
         }
     return specs
@@ -159,7 +166,9 @@ def _apply_sublayer(
 ) -> Tuple[jax.Array, jax.Array]:
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, lp["norm1"], cfg.norm_eps)
-    if kind == "attn":
+    if kind == "attn" and cfg.mla:
+        h, _ = mla_full(lp["attn"], h, cfg, positions)
+    elif kind == "attn":
         h = attention_full(lp["attn"], h, cfg, positions, window=cfg.attn_window, impl=impl)
     elif kind == "rec":
         h = rglru_full(lp["rec"], h, cfg, impl=impl)
@@ -170,10 +179,28 @@ def _apply_sublayer(
     x = _residual(cfg, x, h)
     h = rms_norm(x, lp["norm2"], cfg.norm_eps)
     if "moe" in lp:
-        h, aux = moe_apply(lp["moe"], h, cfg)
+        h, stats = moe_apply(lp["moe"], h, cfg)
+        aux = stats["aux"]
     else:
         h = mlp_apply(lp["mlp"], h, cfg)
     return _residual(cfg, x, h), aux
+
+
+def _ffn(cfg: ModelConfig, lp: Dict, h: jax.Array, counts: Dict,
+         decode: bool = False) -> Tuple[jax.Array, Dict]:
+    """A serving step's MLP or experts; ``counts`` (the experts' ``pairs``
+    and ``dropped``, where the model has experts) gain the layer's."""
+    if "moe" not in lp:
+        return mlp_apply(lp["mlp"], h, cfg), counts
+    y, stats = moe_apply(lp["moe"], h, cfg, decode=decode)
+    return y, {k: counts[k] + stats[k] for k in counts}
+
+
+def _no_counts(cfg: ModelConfig) -> Dict:
+    """The serving counters of a model with experts, at 0; none without."""
+    if cfg.moe is None:
+        return {}
+    return {"pairs": jnp.zeros((), jnp.int32), "dropped": jnp.zeros((), jnp.int32)}
 
 
 def _run_groups(
@@ -257,11 +284,19 @@ def loss_and_aux(
 
 # -------------------------------------------------------------------- decode
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
+    """The decode cache: ``t``, per layer K/V (``k``, ``v``), the latent of
+    MLA (``c_kv``, ``k_pe``) or recurrent state; with experts, ``moe``: the
+    (token, held expert) pairs routed (``pairs``) and dropped (``dropped``)
+    since the prompt's first token."""
     cache: Dict[str, Any] = {"t": jnp.zeros((), jnp.int32)}
+    if cfg.moe is not None:
+        cache["moe"] = _no_counts(cfg)
     for gi, (pattern, rep) in enumerate(cfg.groups):
         g: Dict[str, Any] = {}
         for pi, kind in enumerate(pattern):
-            if kind == "attn":
+            if kind == "attn" and cfg.mla:
+                g[f"pos{pi}"] = init_latent_cache(cfg, rep, batch, max_len)
+            elif kind == "attn":
                 g[f"pos{pi}"] = init_kv_cache(cfg, rep, batch, max_len, window=cfg.attn_window)
             elif kind == "rec":
                 g[f"pos{pi}"] = rglru_init_state(cfg, rep, batch)
@@ -276,17 +311,22 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
 def rewritten_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
     """The decode cache's subtrees (``group<g>/pos<p>``) that every decode
     step rewrites whole: the recurrent state of each non-attention layer.
-    Attention K/V gain one position a step, and ``t`` is a counter."""
+    Attention K/V (and MLA's latent) gain one position a step; ``t`` and
+    ``moe`` are counters."""
     return tuple(f"group{gi}/pos{pi}" for gi, (pattern, _) in enumerate(cfg.groups)
                  for pi, kind in enumerate(pattern) if kind != "attn")
 
 
 def cache_specs(cfg: ModelConfig, tp: int = 16) -> Dict:
     specs: Dict[str, Any] = {"t": ()}
+    if cfg.moe is not None:
+        specs["moe"] = {"pairs": (), "dropped": ()}
     for gi, (pattern, rep) in enumerate(cfg.groups):
         g: Dict[str, Any] = {}
         for pi, kind in enumerate(pattern):
-            if kind == "attn":
+            if kind == "attn" and cfg.mla:
+                g[f"pos{pi}"] = latent_cache_specs()
+            elif kind == "attn":
                 g[f"pos{pi}"] = kv_cache_specs(cfg, tp)
             elif kind == "rec":
                 g[f"pos{pi}"] = rglru_state_specs()
@@ -306,19 +346,24 @@ def decode_step(
     x = _embed_tokens(cfg, params, token)
     x = with_logical(x, "batch", None, None)
     new_cache: Dict[str, Any] = {"t": t + 1}
+    counts = _no_counts(cfg)
 
     for gi, (pattern, rep) in enumerate(cfg.groups):
         gparams = params[f"group{gi}"]
         gcache = cache[f"group{gi}"]
 
-        def body(h, xs, pattern=pattern):
+        def body(carry, xs, pattern=pattern):
+            h, counts = carry
             layer_params, layer_cache = xs
             new_layer_cache = {}
             for pi, kind in enumerate(pattern):
                 lp = layer_params[f"pos{pi}"]
                 lc = layer_cache[f"pos{pi}"]
                 hin = rms_norm(h, lp["norm1"], cfg.norm_eps)
-                if kind == "attn":
+                if kind == "attn" and cfg.mla:
+                    y, c_kv, k_pe = mla_decode(lp["attn"], hin, lc["c_kv"], lc["k_pe"], cfg, t)
+                    new_layer_cache[f"pos{pi}"] = {"c_kv": c_kv, "k_pe": k_pe}
+                elif kind == "attn":
                     y, ck, cv = attention_decode(
                         lp["attn"], hin, lc["k"], lc["v"], cfg, t, window=cfg.attn_window
                     )
@@ -333,16 +378,15 @@ def decode_step(
                     y, ssm, conv = mamba_decode_step(lp["mamba"], hin, lc["ssm"], lc["conv"], cfg)
                     new_layer_cache[f"pos{pi}"] = {"ssm": ssm, "conv": conv}
                 h = _residual(cfg, h, y)
-                hin = rms_norm(h, lp["norm2"], cfg.norm_eps)
-                if "moe" in lp:
-                    y, _ = moe_apply(lp["moe"], hin, cfg, decode=True)
-                else:
-                    y = mlp_apply(lp["mlp"], hin, cfg)
+                y, counts = _ffn(cfg, lp, rms_norm(h, lp["norm2"], cfg.norm_eps), counts,
+                                 decode=True)
                 h = _residual(cfg, h, y)
-            return h, new_layer_cache
+            return (h, counts), new_layer_cache
 
-        x, new_gcache = jax.lax.scan(body, x, (gparams, gcache))
+        (x, counts), new_gcache = jax.lax.scan(body, (x, counts), (gparams, gcache))
         new_cache[f"group{gi}"] = new_gcache
+    if cfg.moe is not None:
+        new_cache["moe"] = {k: cache["moe"][k] + v for k, v in counts.items()}
     return _logits(cfg, params, x), new_cache
 
 
@@ -362,17 +406,20 @@ def prefill(
     B, S, _ = x.shape
     positions = jnp.arange(S, dtype=jnp.int32)
     cache: Dict[str, Any] = {"t": jnp.asarray(S, jnp.int32)}
+    counts = _no_counts(cfg)
 
     for gi, (pattern, rep) in enumerate(cfg.groups):
         gparams = params[f"group{gi}"]
 
         def body(carry, layer_params, pattern=pattern):
-            h = carry
+            h, counts = carry
             new_layer_cache = {}
             for pi, kind in enumerate(pattern):
                 lp = layer_params[f"pos{pi}"]
                 hin = rms_norm(h, lp["norm1"], cfg.norm_eps)
-                if kind == "attn":
+                if kind == "attn" and cfg.mla:
+                    y, new_layer_cache[f"pos{pi}"] = mla_full(lp["attn"], hin, cfg, positions)
+                elif kind == "attn":
                     y = attention_full(lp["attn"], hin, cfg, positions,
                                        window=cfg.attn_window, impl=impl)
                     new_layer_cache[f"pos{pi}"] = _kv_for_cache(cfg, lp["attn"], hin, positions)
@@ -385,16 +432,14 @@ def prefill(
                 elif kind == "mamba":
                     y, new_layer_cache[f"pos{pi}"] = mamba_full(lp["mamba"], hin, cfg)
                 h = _residual(cfg, h, y)
-                hin = rms_norm(h, lp["norm2"], cfg.norm_eps)
-                if "moe" in lp:
-                    y, _ = moe_apply(lp["moe"], hin, cfg)
-                else:
-                    y = mlp_apply(lp["mlp"], hin, cfg)
+                y, counts = _ffn(cfg, lp, rms_norm(h, lp["norm2"], cfg.norm_eps), counts)
                 h = _residual(cfg, h, y)
-            return h, new_layer_cache
+            return (h, counts), new_layer_cache
 
-        x, gcache = jax.lax.scan(body, x, gparams)
+        (x, counts), gcache = jax.lax.scan(body, (x, counts), gparams)
         cache[f"group{gi}"] = gcache
+    if cfg.moe is not None:
+        cache["moe"] = counts
     logits = _logits(cfg, params, x[:, -1:, :])
     return logits[:, 0, :], cache
 

@@ -78,12 +78,12 @@ def test_decode_step_shapes(name):
 
 
 def test_all_archs_registered():
-    assert len(ALL) == 11
+    assert len(ALL) == 12
     assert set(ALL) == {
         "musicgen-medium", "minitron-8b", "granite-8b", "stablelm-1.6b",
         "nemotron-4-340b", "recurrentgemma-9b", "rwkv6-3b",
         "llama4-scout-17b-a16e", "qwen2-moe-a2.7b", "internvl2-76b",
-        "granite-4.0-h-micro",
+        "granite-4.0-h-micro", "deepseek-v2-lite",
     }
 
 
